@@ -60,7 +60,6 @@ from repro.kernels.im2col_pack.ops import (
     im2col_pack,
 )
 from repro.kernels.im2col_pack.ref import out_size
-from repro.kernels.pltpu_compat import HAS_ASYNC_COPY
 
 SPARSITY = 0.5
 V = 128
@@ -105,14 +104,12 @@ def _pipelined(x, values, idx, *, kh, kw, stride, pad, v):
 
 
 # (name, fn, needs_fused_feasible): plans gated on the VMEM-resident
-# predicate only run where a real TPU could run them; the manual-DMA plans
-# only exist on async-copy-capable pallas builds (same gate as their
-# dispatch predicates — the bench degrades to the PR-3 plan set, not a crash)
+# predicate only run where a real TPU could run them
 PLANS = [
     ("fused", conv2d_fused, True),
-    *([("banded", _banded, False)] if HAS_ASYNC_COPY else []),
+    ("banded", _banded, False),
     ("two_kernel", conv2d_two_kernel, False),
-    *([("pipelined", _pipelined, False)] if HAS_ASYNC_COPY else []),
+    ("pipelined", _pipelined, False),
     ("transposed", _transposed, False),
     ("xla", conv2d_xla_ref, False),
 ]

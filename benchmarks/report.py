@@ -12,7 +12,11 @@ from pathlib import Path
 from typing import Dict, List, Optional
 
 from repro.configs import SHAPES, get_config
-from repro.roofline.analysis import PEAK_FLOPS, model_flops_for
+from repro.roofline.analysis import (
+    DRYRUN_DEVICE_KIND,
+    model_flops_for,
+    peaks_for,
+)
 
 ART = Path("artifacts/dryrun")
 
@@ -29,7 +33,7 @@ def refresh_roofline(rec: Dict) -> Dict:
     total = r["flops_per_chip"] * r["chips"]
     r["useful_flops_ratio"] = mf / total if total else 0.0
     t_bound = max(r["t_compute_s"], r["t_memory_s"], r["t_collective_s"])
-    ideal = mf / r["chips"] / PEAK_FLOPS
+    ideal = mf / r["chips"] / peaks_for(DRYRUN_DEVICE_KIND).flops
     r["roofline_fraction"] = ideal / t_bound if t_bound else 0.0
     return rec
 

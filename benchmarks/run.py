@@ -77,6 +77,9 @@ def main(argv=None) -> None:
                     help="enable the obs layer and write a Perfetto-loadable "
                          "Chrome trace of the whole run to PATH")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.trace:
         from repro import obs

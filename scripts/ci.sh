@@ -52,14 +52,8 @@ python -m benchmarks.bench_conv_fused --quick --json
 
 echo "== banded conv smoke (forced double-buffered DMA path) =="
 REPRO_DISPATCH_FORCE=fused_banded_pallas python - <<'PY'
-import sys
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import SparsityConfig, conv_init, conv_apply, unbox_tree
-from repro.kernels.pltpu_compat import HAS_ASYNC_COPY
-
-if not HAS_ASYNC_COPY:  # same gate as the banded dispatch predicates
-    print("banded DMA smoke SKIPPED: pallas build has no make_async_copy")
-    sys.exit(0)
 cfg = SparsityConfig(sparsity=0.5, m=None, tile=8, min_dim=8,
                      format="compressed_pallas")
 params, _ = unbox_tree(conv_init(jax.random.PRNGKey(0), 8, 16, 3, 3, cfg))

@@ -77,7 +77,9 @@ def recompute_vmem(spec, key) -> Optional[int]:
         return gk.fused_vmem_bytes(
             key.get("c"), max(key.get("b", 1), 1), key.get("h"),
             key.get("w", key.get("h")), geom["v"],
-            min(geom["bk"], key.k_kept), tile, in_bytes=ib)
+            min(geom["bk"], key.k_kept), tile, in_bytes=ib,
+            kh=key.get("kh"), kw=key.get("kw"), stride=key.get("s", 1),
+            pad=key.get("p", 0))
     if family == "fused_banded_pallas":
         c, h = key.get("c"), key.get("h")
         w = key.get("w", h)
@@ -87,7 +89,8 @@ def recompute_vmem(spec, key) -> Optional[int]:
         _, band_rows = band_rows_for(gk, b, h, key, ho, wo, geom)
         return gk.banded_vmem_bytes(c, w, band_rows, geom["v"],
                                     min(geom["bk"], key.k_kept), tile,
-                                    in_bytes=ib)
+                                    in_bytes=ib,
+                                    taps=key.get("kh") * key.get("kw"))
     if family == "two_kernel_pipelined":
         return ck.pipelined_strips_vmem_bytes(
             key.d_in, geom["v"], geom["hb"], min(geom["bk"], key.k_kept),

@@ -125,7 +125,7 @@ def pallas_models(tree: ast.Module) -> List[PallasModel]:
         n_prefetch = 0
         grid_spec = _kwarg(node, "grid_spec")
         if isinstance(grid_spec, ast.Call):
-            # prefetch_grid_spec(num_scalar_prefetch=K, in_specs=..., ...):
+            # PrefetchScalarGridSpec(num_scalar_prefetch=K, in_specs=...):
             # scalar-prefetch operands shift every kernel param right by K
             spec_src = grid_spec
             n_prefetch = _const_or(_kwarg(grid_spec, "num_scalar_prefetch"), 0)

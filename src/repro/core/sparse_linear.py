@@ -172,6 +172,33 @@ def forward_masked(x: jax.Array, w: jax.Array, mask: jax.Array) -> jax.Array:
     return x @ (w * mask.astype(w.dtype))
 
 
+def _per_shard(fn, x: jax.Array, values: jax.Array, idx: jax.Array):
+    """``fn(x, values, idx)`` of a compressed linear, run once per device
+    under ``jax.shard_map`` wherever an installed sharding context would
+    have GSPMD partition it (GSPMD cannot partition a Mosaic kernel; per
+    shard every dispatch candidate sees a single-device operator).
+
+    The tile axis splits over the mesh axes of the ``tile`` rule, so each
+    shard owns whole tiles and a contiguous block of output columns; the
+    leading batch dim splits over ``act_batch``; everything else, the kept
+    dim included, is replicated into the body."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.sharding.api import get_ctx, gspmd_devices, resolve_spec
+
+    if gspmd_devices() <= 1:
+        return fn(x, values, idx)
+    ctx = get_ctx()
+    # one spec, so a mesh axis serves the tile axis or the batch, not both
+    t, b = resolve_spec((values.shape[0], x.shape[0]), ("tile", "act_batch"),
+                        ctx.rules, ctx.mesh)
+    mid = (None,) * (x.ndim - 2)
+    return jax.shard_map(
+        fn, mesh=ctx.mesh,
+        in_specs=(P(b, *mid, None), P(t, None, None), P(t, None)),
+        out_specs=P(b, *mid, t), check_vma=False)(x, values, idx)
+
+
 def linear_apply(params, x: jax.Array, *, prefer_pallas: bool = False,
                  impl: Optional[str] = None) -> jax.Array:
     """Apply a layer created by ``linear_init`` (unboxed params).
@@ -192,16 +219,21 @@ def linear_apply(params, x: jax.Array, *, prefer_pallas: bool = False,
 
         if impl is None and prefer_pallas:
             impl = "compressed_pallas"
-        key = _dispatch.linear_key_from(
-            x.shape, params["values"].shape, x.dtype,
-            phase=_dispatch.current_phase())
-        spec = _dispatch.best_impl(key, param_keys=("values", "idx"),
-                                   force=impl)
-        # execution guard: a candidate that fails to run (trace-time kernel
-        # crash or injected fault) is quarantined and the key re-resolves
-        # down the ladder instead of killing the forward
-        y = _dispatch.run_guarded(key, spec, lambda s: s.apply(params, x),
-                                  param_keys=("values", "idx"))
+
+        def dispatched(x, values, idx):
+            p = {"values": values, "idx": idx}
+            key = _dispatch.linear_key_from(
+                x.shape, values.shape, x.dtype,
+                phase=_dispatch.current_phase())
+            spec = _dispatch.best_impl(key, param_keys=("values", "idx"),
+                                       force=impl)
+            # execution guard: a candidate that fails to run (trace-time
+            # kernel crash or injected fault) is quarantined and the key
+            # re-resolves down the ladder instead of killing the forward
+            return _dispatch.run_guarded(key, spec, lambda s: s.apply(p, x),
+                                         param_keys=("values", "idx"))
+
+        y = _per_shard(dispatched, x, params["values"], params["idx"])
     elif "mask" in params:
         y = forward_masked(x, params["w"], params["mask"])
     else:

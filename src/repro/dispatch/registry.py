@@ -104,11 +104,25 @@ def _dtype_tag(dtype) -> str:
         name, name)
 
 
+def _mesh_extra() -> Tuple[Tuple[str, int], ...]:
+    """``(("mesh", n),)`` for an op that GSPMD would partition over ``n > 1``
+    devices, else ``()`` (single-device tokens keep their format).  GSPMD
+    does not partition Mosaic kernels, so the Pallas predicates refuse such
+    keys, and the profile DB keeps them apart.  An op inside a
+    ``jax.shard_map`` body manual over every mesh axis is per-shard and
+    keys like a single device: the compressed linear runs so under a mesh
+    (``core.sparse_linear.linear_apply``)."""
+    from repro.sharding.api import gspmd_devices
+
+    n = gspmd_devices()
+    return (("mesh", n),) if n > 1 else ()
+
+
 def linear_key(batch: int, d_in: int, d_out: int, k_kept: int, tile: int,
                dtype="float32", phase: str = "") -> OpKey:
     return OpKey(op="linear", batch=bucket_batch(batch), d_in=bucket_dim(d_in),
                  d_out=d_out, k_kept=k_kept, tile=tile, dtype=_dtype_tag(dtype),
-                 phase=phase)
+                 extra=_mesh_extra(), phase=phase)
 
 
 def linear_key_from(x_shape: Sequence[int], values_shape: Sequence[int],
@@ -140,7 +154,8 @@ def conv_key(c: int, h: int, w: int, o: int, kh: int, kw: int, stride: int,
         d_in=kh * kw * c, d_out=o, k_kept=k_kept, tile=tile,
         dtype=_dtype_tag(dtype),
         extra=(("b", batch), ("c", c), ("h", h), ("w", w), ("kh", kh),
-               ("kw", kw), ("s", stride), ("p", pad), ("v", v)),
+               ("kw", kw), ("s", stride), ("p", pad), ("v", v))
+        + _mesh_extra(),
         phase=phase,
     )
 
@@ -277,7 +292,21 @@ def _key_itemsize(key: OpKey) -> int:
     return 4 if key.dtype == "f32" else 2
 
 
+def _unpartitioned(key: OpKey) -> Tuple[bool, str]:
+    """Pallas candidates run only where no GSPMD partitioning is asked of
+    them (the compiler: "Mosaic kernels cannot be automatically
+    partitioned")."""
+    n = key.get("mesh", 1)
+    if n > 1:
+        return False, (f"Mosaic kernels cannot be automatically partitioned "
+                       f"(traced under a {n}-device sharded mesh)")
+    return True, "ok"
+
+
 def _tile_ok(key: OpKey) -> Tuple[bool, str]:
+    ok, reason = _unpartitioned(key)
+    if not ok:
+        return ok, reason
     if key.d_out % key.tile != 0:
         return False, f"d_out={key.d_out} not divisible by tile={key.tile}"
     if key.tile % 8 != 0:
@@ -549,7 +578,9 @@ def _fused_vmem_for(geom_v: int, geom_bk: int):
         return fused_vmem_bytes(
             key.get("c"), max(key.get("b", 1), 1), key.get("h"),
             key.get("w", key.get("h")), geom_v, min(geom_bk, key.k_kept),
-            min(key.tile, 512), in_bytes=_key_itemsize(key))
+            min(key.tile, 512), in_bytes=_key_itemsize(key),
+            kh=key.get("kh"), kw=key.get("kw"), stride=key.get("s", 1),
+            pad=key.get("p", 0))
 
     return vm
 
@@ -646,26 +677,22 @@ def _banded_vmem_for(geom_v: int, geom_bk: int, geom_hb: int):
                                  ho=ho, wo=wo, v=geom_v, hb=geom_hb)
         return banded_vmem_bytes(c, w, band_rows, geom_v,
                                  min(geom_bk, key.k_kept), min(key.tile, 512),
-                                 in_bytes=_key_itemsize(key))
+                                 in_bytes=_key_itemsize(key),
+                                 taps=key.get("kh") * key.get("kw"))
 
     return vm
 
 
 def _dma_conv_feasible_for(vm_fn):
     """Predicate factory shared by the manual-DMA conv plans: tile shape,
-    conv extras present, an async-copy-capable pallas build, and the
-    double-buffered footprint within budget."""
+    conv extras present, and the double-buffered footprint within budget."""
 
     def feasible(key: OpKey) -> Tuple[bool, str]:
-        from repro.kernels.pltpu_compat import HAS_ASYNC_COPY
-
         ok, reason = _tile_ok(key)
         if not ok:
             return ok, reason
         if key.get("c") <= 0 or key.get("h") <= 0:
             return False, "conv geometry (c, h, w) missing from key extras"
-        if not HAS_ASYNC_COPY:
-            return False, "pallas build has no make_async_copy"
         vm = vm_fn(key)
         if vm > VMEM_BYTES:
             return False, f"VMEM {vm} > budget {VMEM_BYTES}"
@@ -759,6 +786,7 @@ def paged_attn_key(q_rows: int, n_heads: int, kv_heads: int, head_dim: int,
     extra = (("hd", head_dim), ("kvcap", bucket_batch(max(kv_capacity, 1))))
     if page_size:
         extra += (("ps", page_size),)
+    extra += _mesh_extra()
     return OpKey(op="paged_attn", batch=bucket_batch(max(q_rows, 1)),
                  d_in=head_dim, d_out=n_heads * head_dim, k_kept=kv_heads,
                  tile=8, dtype=_dtype_tag(dtype), extra=extra, phase=phase)
@@ -778,10 +806,9 @@ def _paged_vmem_for(geom_ps: int, geom_bq: int):
 
 def _paged_feasible_for(geom_ps: int, geom_bq: int):
     def feasible(key: OpKey) -> Tuple[bool, str]:
-        from repro.kernels.flash_attn.paged import paged_kernel_available
-
-        if not paged_kernel_available():
-            return False, "pallas build lacks async-copy or scalar prefetch"
+        ok, reason = _unpartitioned(key)
+        if not ok:
+            return ok, reason
         hd, kv = key.get("hd"), key.k_kept
         if hd <= 0 or kv <= 0:
             return False, "paged geometry (hd, kv) missing from key extras"

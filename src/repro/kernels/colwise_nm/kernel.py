@@ -9,7 +9,7 @@ TPU adaptation of the RVV micro-kernel:
   scalar weight × data         dense [block_b, block_k] × [block_k, T] MXU
   vector vfmacc per kept       matmul per kept-column *chunk* (the gather of
   column                       block_k kept columns happens in VMEM first)
-  indexed vector load of the   lane-dimension gather ``x_blk[:, ids]`` from
+  indexed vector load of the   one-hot MXU gather of the kept columns from
   data-matrix row              the VMEM-resident activation block
   LMUL / vector length         block_k, tile width T (lane multiples of 128)
 
@@ -32,13 +32,44 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pltpu_compat import COMPILER_PARAMS as _COMPILER_PARAMS
 from repro.kernels.pltpu_compat import (
-    MEM_ANY,
+    MEM_HBM,
     ceil_to,
     dma_semaphores,
     dot_f32,
     double_buffer_rotate,
+    gather_cols,
+    gather_rows,
+    gather_vmem_bytes,
     make_async_copy,
 )
+
+
+def chunk_kept(values: jax.Array, idx: jax.Array, block_k: int):
+    """Split the kept (reduction) axis into ``block_k``-row chunks.
+
+    ``block_k`` is capped at the 8-aligned kept count, and zero-valued
+    padding rows fill the last chunk (they gather index 0 but multiply by 0
+    weights).  ``idx`` becomes ``[n_tiles * n_kc, 1, block_k]``: one
+    lane-vector block per (tile, chunk) grid step (see :func:`idx_spec`) —
+    a ``(1, 1, block_k)`` block spans the array's two minor dims, which
+    Mosaic accepts for any ``block_k``; a ``(1, block_k)`` block of the 2-D
+    ``idx`` does not.  Returns ``(values, idx_chunks, block_k, n_kc)``.
+    """
+    n_tiles, k_kept, _ = values.shape
+    block_k = min(block_k, ceil_to(k_kept, 8))
+    k_pad = ceil_to(k_kept, block_k)
+    if k_pad != k_kept:
+        values = jnp.pad(values, ((0, 0), (0, k_pad - k_kept), (0, 0)))
+        idx = jnp.pad(idx, ((0, 0), (0, k_pad - k_kept)))
+    n_kc = k_pad // block_k
+    return values, idx.reshape(n_tiles * n_kc, 1, block_k), block_k, n_kc
+
+
+def idx_spec(block_k: int, n_kc: int) -> pl.BlockSpec:
+    """BlockSpec of :func:`chunk_kept`'s idx for a ``(rows, tile, k-chunk)``
+    grid."""
+    return pl.BlockSpec((1, 1, block_k),
+                        lambda i, t, kc: (t * n_kc + kc, 0, 0))
 
 
 def _kernel(x_ref, idx_ref, v_ref, o_ref, acc_ref, *, n_kc: int, out_dtype, interpret: bool):
@@ -48,13 +79,11 @@ def _kernel(x_ref, idx_ref, v_ref, o_ref, acc_ref, *, n_kc: int, out_dtype, inte
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ids = idx_ref[0]  # [block_k] int32 — kept d_in indices for this chunk
-    x_blk = x_ref[...]  # [block_b, d_in] activation rows (VMEM resident)
+    ids = idx_ref[0]  # [1, block_k] int32 — kept d_in indices for this chunk
     # In-VMEM gather of the kept columns: the fusion of "im2col/packing" style
     # data movement into the compute kernel — the gathered operand never
-    # exists in HBM.  (Mosaic: lane-dim dynamic_gather; validated via
-    # interpret mode on CPU.)
-    x_sel = jnp.take(x_blk, ids, axis=1)  # [block_b, block_k]
+    # exists in HBM.
+    x_sel = gather_cols(x_ref, ids, interpret).astype(x_ref.dtype)
     acc_ref[...] += dot_f32(x_sel, v_ref[0], interpret)
 
     @pl.when(kc == n_kc - 1)
@@ -81,19 +110,12 @@ def colwise_nm_matmul_pallas(
     assert idx.shape == (n_tiles, k_kept), (idx.shape, values.shape)
 
     block_b = min(block_b, ceil_to(B, 8))
-    block_k = min(block_k, ceil_to(k_kept, 8))
-
     b_pad = ceil_to(B, block_b)
-    k_pad = ceil_to(k_kept, block_k)
     if b_pad != B:
         x = jnp.pad(x, ((0, b_pad - B), (0, 0)))
-    if k_pad != k_kept:
-        # zero-valued padding rows gather x[:, 0] but multiply by 0 weights
-        values = jnp.pad(values, ((0, 0), (0, k_pad - k_kept), (0, 0)))
-        idx = jnp.pad(idx, ((0, 0), (0, k_pad - k_kept)))
+    values, idx, block_k, n_kc = chunk_kept(values, idx, block_k)
 
     n_b = b_pad // block_b
-    n_kc = k_pad // block_k
     grid = (n_b, n_tiles, n_kc)
 
     out = pl.pallas_call(
@@ -101,7 +123,7 @@ def colwise_nm_matmul_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, d_in), lambda i, t, kc: (i, 0)),
-            pl.BlockSpec((1, block_k), lambda i, t, kc: (t, kc)),
+            idx_spec(block_k, n_kc),
             pl.BlockSpec((1, block_k, tile), lambda i, t, kc: (t, kc, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, tile), lambda i, t, kc: (i, t)),
@@ -128,12 +150,13 @@ def _strips_kernel(x_ref, idx_ref, v_ref, o_ref, acc_ref, *, n_kc: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ids = idx_ref[0]  # [block_k] kept reduction rows for this chunk
-    x_blk = x_ref[0]  # [K, V] one packed strip, VMEM resident
-    # sublane-dim gather of the kept strip *rows* — the strips already sit in
-    # the paper's packed layout, so no transpose/relayout ever happens in HBM
-    x_sel = jnp.take(x_blk, ids, axis=0)  # [block_k, V]
-    acc_ref[...] += dot_f32(v_ref[0].T, x_sel, interpret)  # [tile, V]
+    ids = idx_ref[0]  # [1, block_k] kept reduction rows for this chunk
+    # gather of the kept strip *rows* from the VMEM-resident [K, V] strip —
+    # the strips already sit in the paper's packed layout, so no
+    # transpose/relayout ever happens in HBM
+    x_sel = gather_rows(x_ref.at[0], ids, interpret).astype(x_ref.dtype)
+    acc_ref[...] += dot_f32(v_ref[0], x_sel, interpret,
+                            trans_a=True)  # [tile, V]
 
     @pl.when(kc == n_kc - 1)
     def _flush():
@@ -160,12 +183,7 @@ def colwise_nm_matmul_strips_pallas(
     n_tiles, k_kept, tile = values.shape
     assert idx.shape == (n_tiles, k_kept), (idx.shape, values.shape)
 
-    block_k = min(block_k, ceil_to(k_kept, 8))
-    k_pad = ceil_to(k_kept, block_k)
-    if k_pad != k_kept:
-        values = jnp.pad(values, ((0, 0), (0, k_pad - k_kept), (0, 0)))
-        idx = jnp.pad(idx, ((0, 0), (0, k_pad - k_kept)))
-    n_kc = k_pad // block_k
+    values, idx, block_k, n_kc = chunk_kept(values, idx, block_k)
 
     grid = (n_strips, n_tiles, n_kc)
     out = pl.pallas_call(
@@ -174,7 +192,7 @@ def colwise_nm_matmul_strips_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, d_in, v), lambda s, t, kc: (s, 0, 0)),
-            pl.BlockSpec((1, block_k), lambda s, t, kc: (t, kc)),
+            idx_spec(block_k, n_kc),
             pl.BlockSpec((1, block_k, tile), lambda s, t, kc: (t, kc, 0)),
         ],
         out_specs=pl.BlockSpec((tile, v), lambda s, t, kc: (t, s)),
@@ -193,7 +211,7 @@ def strips_vmem_bytes(d_in: int, v: int, block_k: int, tile: int,
                       in_bytes: int = 2) -> int:
     """Analytic VMEM footprint of one strip-major grid step."""
     strip = d_in * v * in_bytes
-    x_sel = block_k * v * in_bytes
+    x_sel = block_k * v * in_bytes + gather_vmem_bytes(block_k, v, in_bytes)
     v_blk = block_k * tile * in_bytes
     acc = tile * v * 4
     out = tile * v * in_bytes
@@ -248,10 +266,11 @@ def _strips_pipelined_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ids = idx_ref[0]
-    x_blk = buf_ref[(g % 2) * hb + (s - origin(g))]  # [K, V], VMEM resident
-    x_sel = jnp.take(x_blk, ids, axis=0)  # [block_k, V]
-    acc_ref[...] += dot_f32(v_ref[0].T, x_sel, interpret)  # [tile, V]
+    ids = idx_ref[0]  # [1, block_k]
+    x_blk = buf_ref.at[(g % 2) * hb + (s - origin(g))]  # [K, V], VMEM
+    x_sel = gather_rows(x_blk, ids, interpret).astype(buf_ref.dtype)
+    acc_ref[...] += dot_f32(v_ref[0], x_sel, interpret,
+                            trans_a=True)  # [tile, V]
 
     @pl.when(kc == n_kc - 1)
     def _flush():
@@ -281,12 +300,7 @@ def colwise_nm_matmul_strips_pipelined_pallas(
     hb = max(min(hb, n_strips), 1)
     n_chunks = -(-n_strips // hb)
 
-    block_k = min(block_k, ceil_to(k_kept, 8))
-    k_pad = ceil_to(k_kept, block_k)
-    if k_pad != k_kept:
-        values = jnp.pad(values, ((0, 0), (0, k_pad - k_kept), (0, 0)))
-        idx = jnp.pad(idx, ((0, 0), (0, k_pad - k_kept)))
-    n_kc = k_pad // block_k
+    values, idx, block_k, n_kc = chunk_kept(values, idx, block_k)
 
     grid = (n_strips, n_tiles, n_kc)
     out = pl.pallas_call(
@@ -296,8 +310,8 @@ def colwise_nm_matmul_strips_pipelined_pallas(
             interpret=interpret),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=MEM_ANY),  # strips stay in HBM
-            pl.BlockSpec((1, block_k), lambda s, t, kc: (t, kc)),
+            pl.BlockSpec(memory_space=MEM_HBM),  # strips stay in HBM
+            idx_spec(block_k, n_kc),
             pl.BlockSpec((1, block_k, tile), lambda s, t, kc: (t, kc, 0)),
         ],
         out_specs=pl.BlockSpec((tile, v), lambda s, t, kc: (t, s)),
@@ -324,7 +338,7 @@ def pipelined_strips_vmem_bytes(d_in: int, v: int, hb: int, block_k: int,
     chunks of ``hb`` strips (double buffer) plus the gather/weight/acc/out
     tiles of the plain strip-major kernel."""
     chunks = 2 * hb * d_in * v * in_bytes
-    x_sel = block_k * v * in_bytes
+    x_sel = block_k * v * in_bytes + gather_vmem_bytes(block_k, v, in_bytes)
     v_blk = block_k * tile * in_bytes
     acc = tile * v * 4
     out = tile * v * in_bytes
@@ -334,7 +348,8 @@ def pipelined_strips_vmem_bytes(d_in: int, v: int, hb: int, block_k: int,
 def vmem_bytes(block_b: int, block_k: int, d_in: int, tile: int, in_bytes: int = 2) -> int:
     """Analytic VMEM footprint of one grid step (for the auto-tuner)."""
     x_blk = block_b * d_in * in_bytes
-    x_sel = block_b * block_k * in_bytes
+    x_sel = (block_b * block_k * in_bytes
+             + gather_vmem_bytes(block_b, block_k, in_bytes))
     v_blk = block_k * tile * in_bytes
     acc = block_b * tile * 4
     out = block_b * tile * in_bytes

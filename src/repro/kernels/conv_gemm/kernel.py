@@ -6,21 +6,22 @@ kernel: each packed strip tile is *produced in VMEM* — (kh, kw, c) rows
 gathered straight from the CNHW feature map with the same index arithmetic as
 ``im2col_pack/kernel.py`` — and immediately consumed by the in-VMEM
 kept-column gather + dense MXU matmul of ``colwise_nm/kernel.py``.  The patch
-matrix / packed strips never exist in HBM, and because only the *kept* rows of
-each strip are ever materialized, the gather itself is the sparse compression:
+matrix / packed strips never exist in HBM:
 
   two-kernel path   HBM traffic:  write strips, read strips (transposed
                     relayout!), write GEMM output          — 3 round-trips
   this megakernel   HBM traffic:  read feature map, write output — 0 extra
 
-Grid: (n_strips, n_tiles, k_chunks).  Step (s, t, kc) gathers the block_k
-kept rows of chunk kc for output tile t, restricted to strip s's V output
-positions, multiplies by the [block_k, T] compressed weight chunk, and
-accumulates into a float32 [T, V] VMEM scratch.  The output is written
-directly in [O, P] layout (P padded to n_strips*V), so the caller's final
-``y.T`` relayout disappears as well.  Ragged final strips and out-of-map
-(kh, kw) taps are handled with iota-compare masks exactly as in the
-standalone pack kernel.
+Grid: (n_strips, n_tiles, k_chunks).  At the first (t, kc) step of strip s
+the kernel packs the strip's full [Kh*Kw*C, V] patch into VMEM scratch from
+an aligned row window of the map (``im2col_pack.kernel.tap_tile``).  Every
+step (s, t, kc) then gathers the block_k kept rows of chunk kc for output
+tile t from that patch (a one-hot MXU row gather), multiplies by the
+[block_k, T] compressed weight chunk, and accumulates into a float32 [T, V]
+VMEM scratch.  The output is written directly in [O, P] layout (P padded to
+n_strips*V), so the caller's final ``y.T`` relayout disappears as well.
+Ragged final strips and out-of-map (kh, kw) taps are masked exactly as in
+the standalone pack kernel.
 """
 from __future__ import annotations
 
@@ -33,16 +34,47 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pltpu_compat import COMPILER_PARAMS as _COMPILER_PARAMS
 from repro.kernels.pltpu_compat import (
-    MEM_ANY,
+    MEM_HBM,
     ceil_to,
     dma_semaphores,
     dot_f32,
     double_buffer_rotate,
+    gather_rows,
+    gather_vmem_bytes,
     make_async_copy,
 )
 
-from repro.kernels.im2col_pack.kernel import strip_tap_coords
+from repro.kernels.colwise_nm.kernel import chunk_kept, idx_spec
+from repro.kernels.im2col_pack.kernel import (
+    ROW_ALIGN,
+    band_plan,
+    first_row,
+    pad_rows,
+    strip_window,
+    tap_tile,
+    window_origin,
+    window_plan,
+)
 from repro.kernels.im2col_pack.ref import out_size
+
+
+def _pack_patch(patch_ref, win, org, s, *, kh, kw, c, interpret, **geo):
+    """Write strip ``s``'s full [Kh*Kw*C, v] im2col patch into VMEM scratch
+    ``patch_ref`` from the row window ``win`` (origin ``org``): one
+    :func:`tap_tile` per kernel tap, rows ordered (kh, kw, c) like the
+    compressed weight's reduction dim."""
+    for k in range(kh * kw):
+        patch_ref[k * c:(k + 1) * c, :] = tap_tile(
+            win, org, s, ikh=k // kw, ikw=k % kw, interpret=interpret,
+            **geo).astype(patch_ref.dtype)
+
+
+def _sparse_gemm_step(patch_ref, idx_ref, v_ref, acc_ref, interpret):
+    """One Algorithm-1 step: gather this chunk's kept patch rows and
+    accumulate ``values[t, chunk].T @ rows`` into the [T, v] accumulator."""
+    rows = gather_rows(patch_ref, idx_ref[0], interpret)
+    acc_ref[...] += dot_f32(v_ref[0], rows.astype(patch_ref.dtype), interpret,
+                            trans_a=True)
 
 
 def _kernel(
@@ -50,6 +82,7 @@ def _kernel(
     idx_ref,
     v_ref,
     o_ref,
+    patch_ref,
     acc_ref,
     *,
     kh: int,
@@ -63,33 +96,30 @@ def _kernel(
     w: int,
     ho: int,
     wo: int,
+    win_rows: int,
+    bh_pad: int,
     n_kc: int,
     out_dtype,
     interpret: bool,
 ):
     s = pl.program_id(0)
+    t = pl.program_id(1)
     kc = pl.program_id(2)
+
+    @pl.when((t == 0) & (kc == 0))
+    def _pack():
+        # the packed strip tile is born here, in VMEM, and never touches HBM
+        top = first_row(s * v, h=h, ho=ho, wo=wo, stride=stride, pad=pad)
+        org = window_origin(top, win_rows=win_rows, bh_pad=bh_pad)
+        _pack_patch(patch_ref, x_ref[:, pl.ds(org, win_rows), :], org, s,
+                    kh=kh, kw=kw, c=c, stride=stride, pad=pad, b=b, h=h, w=w,
+                    ho=ho, wo=wo, v=v, interpret=interpret)
 
     @pl.when(kc == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ids = idx_ref[0]  # [block_k] kept (kh, kw, c) row ids for this chunk
-    k_of = ids // c  # kernel-tap index ikh*kw + ikw
-    c_of = ids % c
-    # [block_k, v] source coordinates: row j of the strip tile reads input
-    # channel c_of[j] at tap (ikh[j], ikw[j]) of every position in the strip
-    # (shared im2col index arithmetic — see im2col_pack.kernel)
-    valid, bc, ihc, iwc = strip_tap_coords(
-        s, v=v, ikh=(k_of // kw)[:, None], ikw=(k_of % kw)[:, None],
-        stride=stride, pad=pad, b=b, h=h, w=w, ho=ho, wo=wo)
-    # flat gather from the VMEM-resident feature map — the packed strip tile
-    # is born here and never touches HBM
-    flat = x_ref[...].reshape(c * b * h * w)
-    fidx = ((c_of[:, None] * b + bc[None, :]) * h + ihc) * w + iwc
-    patch = jnp.where(valid, jnp.take(flat, fidx), 0)  # [block_k, v]
-
-    acc_ref[...] += dot_f32(v_ref[0].T, patch, interpret)  # [tile, v]
+    _sparse_gemm_step(patch_ref, idx_ref, v_ref, acc_ref, interpret)
 
     @pl.when(kc == n_kc - 1)
     def _flush():
@@ -123,50 +153,66 @@ def conv2d_fused_pallas(
     n_strips = -(-n_pos // v)
     n_tiles, k_kept, tile = values.shape
     assert idx.shape == (n_tiles, k_kept), (idx.shape, values.shape)
+    win_rows, bh_pad = strip_window(b=b, h=h, kh=kh, stride=stride, pad=pad,
+                                    ho=ho, wo=wo, v=v)
 
-    block_k = min(block_k, ceil_to(k_kept, 8))
-    k_pad = ceil_to(k_kept, block_k)
-    if k_pad != k_kept:
-        # zero-valued padding rows gather row 0 but multiply by 0 weights
-        values = jnp.pad(values, ((0, 0), (0, k_pad - k_kept), (0, 0)))
-        idx = jnp.pad(idx, ((0, 0), (0, k_pad - k_kept)))
-    n_kc = k_pad // block_k
+    values, idx, block_k, n_kc = chunk_kept(values, idx, block_k)
 
     grid = (n_strips, n_tiles, n_kc)
     out = pl.pallas_call(
         functools.partial(
             _kernel, kh=kh, kw=kw, stride=stride, pad=pad, v=v,
-            c=c, b=b, h=h, w=w, ho=ho, wo=wo, n_kc=n_kc,
-            out_dtype=x.dtype, interpret=interpret,
+            c=c, b=b, h=h, w=w, ho=ho, wo=wo, win_rows=win_rows,
+            bh_pad=bh_pad, n_kc=n_kc, out_dtype=x.dtype, interpret=interpret,
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((c, b, h, w), lambda s, t, kc: (0, 0, 0, 0)),
-            pl.BlockSpec((1, block_k), lambda s, t, kc: (t, kc)),
+            pl.BlockSpec((c, bh_pad, w), lambda s, t, kc: (0, 0, 0)),
+            idx_spec(block_k, n_kc),
             pl.BlockSpec((1, block_k, tile), lambda s, t, kc: (t, kc, 0)),
         ],
         out_specs=pl.BlockSpec((tile, v), lambda s, t, kc: (t, s)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * tile, n_strips * v), x.dtype),
-        scratch_shapes=[pltpu.VMEM((tile, v), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((kh * kw * c, v), x.dtype),
+                        pltpu.VMEM((tile, v), jnp.float32)],
         compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # the strip's patch is packed at its (t, kc) == (0, 0) step and
+            # reused by every later tile and chunk of that strip
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, idx, values)
+    )(pad_rows(x, bh_pad), idx, values)
     return out
 
 
-def fused_vmem_bytes(c: int, b: int, h: int, w: int, v: int, block_k: int,
-                     tile: int, in_bytes: int = 2) -> int:
-    """Analytic VMEM footprint of one megakernel grid step: the whole CNHW
-    feature map stays resident (it is the only input the kernel reads), plus
-    the gathered strip tile, weight chunk, accumulator and output tile."""
-    fmap = c * b * h * w * in_bytes
-    patch = block_k * v * in_bytes
+def _conv_step_vmem_bytes(c, w, win_rows, taps, v, block_k, tile,
+                          in_bytes) -> int:
+    """VMEM of one megakernel grid step beyond its map storage: the row
+    window with its column-select temporaries, the packed patch, the
+    kept-row gather, weight chunk, accumulator and output tile."""
+    window = c * win_rows * (w * in_bytes + 2 * v * 4)
+    patch = taps * c * v * in_bytes
+    rows = block_k * v * in_bytes + gather_vmem_bytes(block_k, v, in_bytes)
     v_blk = block_k * tile * in_bytes
     acc = tile * v * 4
     out = tile * v * in_bytes
-    return fmap + patch + v_blk + acc + out
+    return window + patch + rows + v_blk + acc + out
+
+
+def fused_vmem_bytes(c: int, b: int, h: int, w: int, v: int, block_k: int,
+                     tile: int, in_bytes: int = 2, *, kh: int = 1,
+                     kw: int = 1, stride: int = 1, pad: int = 0) -> int:
+    """Analytic VMEM footprint of one megakernel grid step: the whole
+    (row-padded) CNHW map stays resident, double-buffered by the pipeline
+    (it is the only input the kernel reads), plus the per-step working set
+    of :func:`_conv_step_vmem_bytes`."""
+    ho = out_size(h, kh, stride, pad)
+    wo = out_size(w, kw, stride, pad)
+    win_rows, bh_pad = strip_window(b=b, h=h, kh=kh, stride=stride, pad=pad,
+                                    ho=ho, wo=wo, v=v)
+    fmap = 2 * c * bh_pad * w * in_bytes
+    return fmap + _conv_step_vmem_bytes(c, w, win_rows, kh * kw, v, block_k,
+                                        tile, in_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -174,61 +220,14 @@ def fused_vmem_bytes(c: int, b: int, h: int, w: int, v: int, block_k: int,
 # ---------------------------------------------------------------------------
 
 
-def band_plan(*, b: int, h: int, kh: int, stride: int, pad: int, ho: int,
-              wo: int, v: int, hb: int):
-    """Static band geometry for the banded megakernel.
-
-    A *band* groups ``hb`` consecutive strips (``hb*v`` output positions).
-    In the flattened ``(batch*h)`` input-row space the rows a band's strips
-    read are contiguous (consecutive output positions advance monotonically
-    through ``bb*h + oh*stride``, including across batch boundaries), so each
-    band needs one contiguous row window of roughly
-    ``stride * ceil(hb*v / wo) + kh - 1`` rows (the strip rows plus the
-    kh-1 halo).  Returns ``(n_bands, band_rows)`` with ``band_rows`` the
-    exact maximum over bands (ragged final band included), clamped to the
-    full ``b*h`` — the static size of the double-buffered VMEM scratch.
-    """
-    n_pos = b * ho * wo
-    n_strips = -(-n_pos // v)
-    hb = max(min(hb, n_strips), 1)
-    n_bands = -(-n_strips // hb)
-    bh = b * h
-
-    def first_row(p):  # top input row touched by output position p (tap 0)
-        bb, rem = divmod(p, ho * wo)
-        return bb * h + (rem // wo) * stride - pad
-
-    rows = 1
-    for g in range(n_bands):
-        p0 = g * hb * v
-        p1 = min((g + 1) * hb * v, n_pos) - 1
-        r0 = max(first_row(p0), 0)
-        r1 = min(first_row(p1) + kh - 1, bh - 1)
-        rows = max(rows, r1 - r0 + 1)
-    return n_bands, min(rows, bh)
-
-
-def _band_origin(g, *, hb, v, h, ho, wo, pad, stride, bh, band_rows):
-    """First flattened (batch*h) input row of band ``g``'s scratch window —
-    the traced twin of ``band_plan``'s ``first_row``/clamp arithmetic (the
-    kernel recomputes it per band; the DMA start and wait descriptors must
-    agree exactly)."""
-    p0 = g * (hb * v)
-    bb0 = p0 // (ho * wo)
-    oh0 = (p0 % (ho * wo)) // wo
-    r0 = jnp.maximum(bb0 * h + oh0 * stride - pad, 0)
-    # clamp so the fixed-size window never reads past the map's last row; the
-    # window then starts *earlier* than needed, which only widens coverage
-    return jnp.minimum(r0, bh - band_rows)
-
-
 def _banded_kernel(
-    x_ref,        # [C, B*H, W] feature map, NOT block-mapped (HBM / ANY)
+    x_ref,        # [C, bh_pad, W'] row/lane-padded feature map, in HBM
     idx_ref,
     v_ref,
     o_ref,
-    band_ref,     # [2, C, band_rows, W] double-buffered row-band scratch
+    band_ref,     # [2, C, win_rows, W'] double-buffered row-window scratch
     sem_ref,      # [2] DMA completion semaphores
+    patch_ref,
     acc_ref,
     *,
     kh: int,
@@ -237,7 +236,8 @@ def _banded_kernel(
     pad: int,
     v: int,
     hb: int,
-    band_rows: int,
+    win_rows: int,
+    bh_pad: int,
     n_bands: int,
     c: int,
     b: int,
@@ -253,15 +253,17 @@ def _banded_kernel(
     t = pl.program_id(1)
     kc = pl.program_id(2)
     g = s // hb
-    bh = b * h
 
     def origin(gi):
-        return _band_origin(gi, hb=hb, v=v, h=h, ho=ho, wo=wo, pad=pad,
-                            stride=stride, bh=bh, band_rows=band_rows)
+        # the DMA start and wait descriptors must agree exactly: both
+        # recompute the band's window origin from its first position
+        top = first_row(gi * (hb * v), h=h, ho=ho, wo=wo, stride=stride,
+                        pad=pad)
+        return window_origin(top, win_rows=win_rows, bh_pad=bh_pad)
 
     def band_dma(slot, gi):
         return make_async_copy(
-            x_ref.at[:, pl.ds(origin(gi), band_rows), :],
+            x_ref.at[:, pl.ds(origin(gi), win_rows), :],
             band_ref.at[slot],
             sem_ref.at[slot],
         )
@@ -272,25 +274,19 @@ def _banded_kernel(
     double_buffer_rotate(band_dma, g, n_bands,
                          gate=(s % hb == 0) & (t == 0) & (kc == 0))
 
+    @pl.when((t == 0) & (kc == 0))
+    def _pack():
+        # same strip packing as the resident megakernel, from the band's
+        # window instead of the resident map
+        _pack_patch(patch_ref, band_ref[g % 2], origin(g), s, kh=kh, kw=kw,
+                    c=c, stride=stride, pad=pad, b=b, h=h, w=w, ho=ho, wo=wo,
+                    v=v, interpret=interpret)
+
     @pl.when(kc == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ids = idx_ref[0]  # [block_k] kept (kh, kw, c) row ids for this chunk
-    k_of = ids // c
-    c_of = ids % c
-    # band-local im2col coordinates: same index arithmetic as the resident
-    # megakernel, with rows rebased to this band's scratch window
-    org = origin(g)
-    valid, rowc, iwc = strip_tap_coords(
-        s, v=v, ikh=(k_of // kw)[:, None], ikw=(k_of % kw)[:, None],
-        stride=stride, pad=pad, b=b, h=h, w=w, ho=ho, wo=wo,
-        band_origin=org, band_rows=band_rows)
-    flat = band_ref[g % 2].reshape(c * band_rows * w)
-    fidx = (c_of[:, None] * band_rows + rowc) * w + iwc
-    patch = jnp.where(valid, jnp.take(flat, fidx), 0)  # [block_k, v]
-
-    acc_ref[...] += dot_f32(v_ref[0].T, patch, interpret)  # [tile, v]
+    _sparse_gemm_step(patch_ref, idx_ref, v_ref, acc_ref, interpret)
 
     @pl.when(kc == n_kc - 1)
     def _flush():
@@ -314,10 +310,11 @@ def conv2d_fused_banded_pallas(
     """H-tiled fused conv: like :func:`conv2d_fused_pallas`, but the feature
     map stays in HBM and only a double-buffered row band is VMEM-resident.
 
-    The map is viewed as [C, B*H, W]; each band (``hb`` strips) DMAs its
-    ``band_rows`` contiguous input rows (strip rows + kh-1 halo) into one of
-    two scratch slots with ``make_async_copy`` while the previous band's
-    gather + Algorithm-1 MXU loop runs.  Output layout and semantics are
+    The map is viewed as [C, B*H, W] rows; each band (``hb`` strips) DMAs
+    its aligned window of input rows (strip rows + kh-1 halo, see
+    :func:`~repro.kernels.im2col_pack.kernel.window_plan`) into one of two
+    scratch slots with ``make_async_copy`` while the previous band's
+    packing + Algorithm-1 MXU loop runs.  Output layout and semantics are
     identical to the resident megakernel — [O, n_strips*V], strip padding
     sliced off by the ops wrapper.
     """
@@ -332,33 +329,30 @@ def conv2d_fused_banded_pallas(
     hb = max(min(hb, n_strips), 1)
     n_bands, band_rows = band_plan(b=b, h=h, kh=kh, stride=stride, pad=pad,
                                    ho=ho, wo=wo, v=v, hb=hb)
+    win_rows, bh_pad = window_plan(band_rows, b * h)
 
-    block_k = min(block_k, ceil_to(k_kept, 8))
-    k_pad = ceil_to(k_kept, block_k)
-    if k_pad != k_kept:
-        values = jnp.pad(values, ((0, 0), (0, k_pad - k_kept), (0, 0)))
-        idx = jnp.pad(idx, ((0, 0), (0, k_pad - k_kept)))
-    n_kc = k_pad // block_k
+    values, idx, block_k, n_kc = chunk_kept(values, idx, block_k)
 
     grid = (n_strips, n_tiles, n_kc)
     out = pl.pallas_call(
         functools.partial(
             _banded_kernel, kh=kh, kw=kw, stride=stride, pad=pad, v=v,
-            hb=hb, band_rows=band_rows, n_bands=n_bands,
+            hb=hb, win_rows=win_rows, bh_pad=bh_pad, n_bands=n_bands,
             c=c, b=b, h=h, w=w, ho=ho, wo=wo, n_kc=n_kc,
             out_dtype=x.dtype, interpret=interpret,
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=MEM_ANY),  # map stays in HBM
-            pl.BlockSpec((1, block_k), lambda s, t, kc: (t, kc)),
+            pl.BlockSpec(memory_space=MEM_HBM),  # map stays in HBM
+            idx_spec(block_k, n_kc),
             pl.BlockSpec((1, block_k, tile), lambda s, t, kc: (t, kc, 0)),
         ],
         out_specs=pl.BlockSpec((tile, v), lambda s, t, kc: (t, s)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * tile, n_strips * v), x.dtype),
         scratch_shapes=[
-            pltpu.VMEM((2, c, band_rows, w), x.dtype),
+            pltpu.VMEM((2, c, win_rows, ceil_to(w, 128)), x.dtype),
             dma_semaphores(2),
+            pltpu.VMEM((kh * kw * c, v), x.dtype),
             pltpu.VMEM((tile, v), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS(
@@ -367,19 +361,18 @@ def conv2d_fused_banded_pallas(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(x.reshape(c, b * h, w), idx, values)
+    )(pad_rows(x, bh_pad, lanes=128), idx, values)
     return out
 
 
 def banded_vmem_bytes(c: int, w: int, band_rows: int, v: int, block_k: int,
-                      tile: int, in_bytes: int = 2) -> int:
-    """Analytic VMEM footprint of one banded-megakernel grid step: TWO row
-    bands (double buffer) instead of the whole map, plus the same gathered
-    strip tile, weight chunk, accumulator and output tile as the resident
-    kernel."""
-    bands = 2 * c * band_rows * w * in_bytes
-    patch = block_k * v * in_bytes
-    v_blk = block_k * tile * in_bytes
-    acc = tile * v * 4
-    out = tile * v * in_bytes
-    return bands + patch + v_blk + acc + out
+                      tile: int, in_bytes: int = 2, *, taps: int = 1) -> int:
+    """Analytic VMEM footprint of one banded-megakernel grid step: TWO
+    aligned row windows (double buffer) of ``band_rows`` needed rows instead
+    of the whole map, plus the resident kernel's per-step working set for a
+    ``taps``-tap (kh*kw) conv."""
+    win_rows = ceil_to(band_rows + ROW_ALIGN - 1, ROW_ALIGN)
+    w = ceil_to(w, 128)  # the HBM map's columns are lane-padded
+    bands = 2 * c * win_rows * w * in_bytes
+    return bands + _conv_step_vmem_bytes(c, w, win_rows, taps, v, block_k,
+                                         tile, in_bytes)

@@ -4,7 +4,6 @@ from repro.kernels.flash_attn.paged import (  # noqa: F401
     paged_attention,
     paged_attention_pallas,
     paged_attention_ref,
-    paged_kernel_available,
     paged_vmem_bytes,
 )
 from repro.kernels.flash_attn.ref import flash_attention_ref  # noqa: F401

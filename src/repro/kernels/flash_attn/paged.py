@@ -6,11 +6,10 @@ physical pages; attention must gather them back. The XLA reference
 rows per sequence round-trip regardless of the actual length. This kernel
 never materializes the gather: the page table is delivered by scalar
 prefetch (SMEM), and one grid dimension walks a sequence's pages
-sequentially, DMA-ing each page from HBM into a two-slot VMEM scratch via
-``pltpu_compat.make_async_copy`` — page j+1 streams in behind page j's
-online-softmax update (the same ``double_buffer_rotate`` protocol as the
-banded conv megakernel). Rows past the sequence's length (ragged final
-page, trash-page table padding) are masked with an explicit probability
+sequentially.  The K/V page operands' index maps read the physical page id
+from the prefetched table, so Pallas' own pipeline DMAs page j+1 from HBM
+while page j's online-softmax update runs. Rows past the sequence's length
+(ragged final page, trash-page table padding) are masked with an explicit probability
 zeroing, so a fully-masked page contributes exactly nothing.
 
 The current step's not-yet-written K/V ("new" keys) are folded in at the
@@ -19,8 +18,11 @@ combine(cache rows < len) ++ new keys is identical math to
 write-then-attend(len + Sq).
 
 Grid: ``(B, Sq/block_q, n_pages)``; pages are the sequential ("arbitrary")
-axis; m/l/acc persist in VMEM scratch across page steps, one lane per KV
-head (GQA groups share their KV head's page DMA).
+axis; m/l/acc persist in VMEM scratch across page steps, one slab per KV
+head (GQA groups share their KV head's page DMA).  The wrapper lays q out
+as ``[B, Sq/block_q, KV, g*block_q, D]`` (group-major rows per KV head), so
+the kernel reads each KV head's ``g*block_q`` query rows as one 2-D tile
+and never reshapes across the sublane tiling — any group size g works.
 """
 from __future__ import annotations
 
@@ -34,15 +36,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pltpu_compat import (
     COMPILER_PARAMS as _COMPILER_PARAMS,
-    HAS_ASYNC_COPY,
-    HAS_SCALAR_PREFETCH,
-    MEM_ANY,
     ceil_to,
-    dma_semaphores,
     dot_f32,
-    double_buffer_rotate,
-    make_async_copy,
-    prefetch_grid_spec,
     should_interpret,
 )
 
@@ -61,19 +56,20 @@ def _flash_update(m_ref, l_ref, acc_ref, kvh, s, mask, v, interpret):
     page phase of a sequence whose cache is still empty.
     """
     s = jnp.where(mask, s, NEG)
-    m_prev = m_ref[kvh]  # [bq*g, 1]
+    m_prev = m_ref[kvh]  # [g*bq, 1]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[kvh] = alpha * l_ref[kvh] + p.sum(axis=-1, keepdims=True)
-    acc_ref[kvh] = alpha * acc_ref[kvh] + dot_f32(p, v, interpret)
+    acc_ref[kvh] = alpha * acc_ref[kvh] + dot_f32(p.astype(v.dtype), v,
+                                                  interpret)
     m_ref[kvh] = m_new
 
 
 def _kernel(tbl_ref, len_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref, o_ref,
-            kscr, vscr, ksem, vsem, m_ref, l_ref, acc_ref, *,
-            n_pages: int, page_size: int, block_q: int, sq: int, sn: int,
-            kv: int, g: int, d: int, scale: float, interpret: bool):
+            m_ref, l_ref, acc_ref, *,
+            n_pages: int, page_size: int, block_q: int, sn: int, kv: int,
+            g: int, scale: float, interpret: bool):
     b = pl.program_id(0)
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -84,58 +80,35 @@ def _kernel(tbl_ref, len_ref, q_ref, kn_ref, vn_ref, kp_ref, vp_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Page DMA: physical page id comes from the scalar-prefetched table.
-    # Padded entries name the trash page — a real, in-range page whose rows
-    # the length mask below always kills.
-    def dma_k(slot, ji):
-        return make_async_copy(kp_ref.at[pl.ds(tbl_ref[b, ji], 1)],
-                               kscr.at[slot], ksem.at[slot])
-
-    def dma_v(slot, ji):
-        return make_async_copy(vp_ref.at[pl.ds(tbl_ref[b, ji], 1)],
-                               vscr.at[slot], vsem.at[slot])
-
-    # Every page is its own grid step, so the rotation gate is always open;
-    # the slot/semaphore pairing restarts cleanly at j == 0 of each (b, i).
-    always = j >= 0
-    double_buffer_rotate(dma_k, j, n_pages, gate=always)
-    double_buffer_rotate(dma_v, j, n_pages, gate=always)
-
-    slot = j % 2
-    kbuf = kscr[slot, 0]  # [ps, KV, D]
-    vbuf = vscr[slot, 0]
-    q = q_ref[0]  # [bq, H, D]
+    # kp_ref/vp_ref hold physical page tbl[b, j] (see the index maps).
+    # Padded table entries name the trash page — a real, in-range page whose
+    # rows the length mask below always kills.
     length = len_ref[b]
-    if interpret:  # XLA:CPU has no bf16 dot
-        q, kbuf, vbuf = (t.astype(jnp.float32) for t in (q, kbuf, vbuf))
 
-    kvpos = j * page_size + jax.lax.iota(jnp.int32, page_size)[None, :]
+    kvpos = j * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, page_size), 1)
     page_mask = kvpos < length  # [1, ps]; causal is implied: qpos >= length
     for h0 in range(kv):
-        qh = q[:, h0 * g:(h0 + 1) * g, :].reshape(block_q * g, d)
-        s = dot_f32(qh, kbuf[:, h0, :].T, interpret) * scale  # [bq*g, ps]
+        qh = q_ref[0, 0, h0]  # [g*bq, D] this KV head's query rows
+        kh = kp_ref[0, :, h0, :]  # [ps, D]
+        s = dot_f32(qh, kh.T, interpret) * scale  # [g*bq, ps]
         _flash_update(m_ref, l_ref, acc_ref, h0, s, page_mask,
-                      vbuf[:, h0, :], interpret)
+                      vp_ref[0, :, h0, :], interpret)
 
     @pl.when(j == n_pages - 1)
     def _new_and_flush():
-        kn = kn_ref[0]  # [sn_p, KV, D]
-        vn = vn_ref[0]
-        qn = q_ref[0]
-        if interpret:
-            kn, vn, qn = (t.astype(jnp.float32) for t in (kn, vn, qn))
-        tpos = jax.lax.iota(jnp.int32, kn.shape[0])[None, :]  # [1, sn_p]
-        qrow = i * block_q + jax.lax.iota(
-            jnp.int32, block_q * g)[:, None] // g  # within-chunk q index
-        new_mask = (tpos <= qrow) & (tpos < sn)
+        tpos = jax.lax.broadcasted_iota(jnp.int32, (1, sn), 1)
+        # rows are group-major: row r is query i*bq + r % bq
+        qrow = i * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (g * block_q, 1), 0) % block_q
+        new_mask = tpos <= qrow
         for h0 in range(kv):
-            qh = qn[:, h0 * g:(h0 + 1) * g, :].reshape(block_q * g, d)
-            s = dot_f32(qh, kn[:, h0, :].T, interpret) * scale
+            qh = q_ref[0, 0, h0]
+            s = dot_f32(qh, kn_ref[0, :, h0, :].T, interpret) * scale
             _flash_update(m_ref, l_ref, acc_ref, h0, s, new_mask,
-                          vn[:, h0, :], interpret)
+                          vn_ref[0, :, h0, :], interpret)
             out = acc_ref[h0] / jnp.maximum(l_ref[h0], 1e-30)
-            o_ref[0, :, h0 * g:(h0 + 1) * g, :] = out.reshape(
-                block_q, g, d).astype(o_ref.dtype)
+            o_ref[0, 0, h0] = out.astype(o_ref.dtype)
 
 
 def paged_attention_pallas(
@@ -167,46 +140,51 @@ def paged_attention_pallas(
     if sq_p != sq:
         pad = ((0, 0), (0, sq_p - sq), (0, 0), (0, 0))
         q = jnp.pad(q, pad)
-    grid = (b, sq_p // block_q, n_pages)
+    nq = sq_p // block_q
+    # [B, Sq_p, H, D] -> [B, nq, KV, g*bq, D], rows group-major per KV head
+    q = (q.reshape(b, nq, block_q, kv, g, d).transpose(0, 1, 3, 4, 2, 5)
+         .reshape(b, nq, kv, g * block_q, d))
+    grid = (b, nq, n_pages)
     sn = k_new.shape[1]
+
+    def page_map(bb, ii, jj, tbl_ref, len_ref):
+        return (tbl_ref[bb, jj], 0, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(
             _kernel, n_pages=n_pages, page_size=page_size, block_q=block_q,
-            sq=sq, sn=sn, kv=kv, g=g, d=d, scale=scale, interpret=interpret,
+            sn=sn, kv=kv, g=g, scale=scale, interpret=interpret,
         ),
-        grid_spec=prefetch_grid_spec(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, block_q, h, d),
-                             lambda bb, ii, jj, *_: (bb, ii, 0, 0)),
+                pl.BlockSpec((1, 1, kv, g * block_q, d),
+                             lambda bb, ii, jj, *_: (bb, ii, 0, 0, 0)),
                 pl.BlockSpec((1, sn, kv, d),
                              lambda bb, ii, jj, *_: (bb, 0, 0, 0)),
                 pl.BlockSpec((1, sn, kv, d),
                              lambda bb, ii, jj, *_: (bb, 0, 0, 0)),
-                pl.BlockSpec(memory_space=MEM_ANY),
-                pl.BlockSpec(memory_space=MEM_ANY),
+                pl.BlockSpec((1, page_size, kv, d), page_map),
+                pl.BlockSpec((1, page_size, kv, d), page_map),
             ],
-            out_specs=pl.BlockSpec((1, block_q, h, d),
-                                   lambda bb, ii, jj, *_: (bb, ii, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, kv, g * block_q, d),
+                                   lambda bb, ii, jj, *_: (bb, ii, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, 1, page_size, kv, d), k_pages.dtype),
-                pltpu.VMEM((2, 1, page_size, kv, d), v_pages.dtype),
-                dma_semaphores(2),
-                dma_semaphores(2),
-                pltpu.VMEM((kv, block_q * g, 1), jnp.float32),
-                pltpu.VMEM((kv, block_q * g, 1), jnp.float32),
-                pltpu.VMEM((kv, block_q * g, d), jnp.float32),
+                pltpu.VMEM((kv, g * block_q, 1), jnp.float32),
+                pltpu.VMEM((kv, g * block_q, 1), jnp.float32),
+                pltpu.VMEM((kv, g * block_q, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, nq, kv, g * block_q, d), q.dtype),
         compiler_params=_COMPILER_PARAMS(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       q, k_new, v_new, k_pages, v_pages)
+    out = (out.reshape(b, nq, kv, g, block_q, d).transpose(0, 1, 4, 2, 3, 5)
+           .reshape(b, sq_p, h, d))
     return out[:, :sq]
 
 
@@ -276,8 +254,3 @@ def paged_attention(q, k_new, v_new, k_pages, v_pages, tables, lengths, *,
                                    lengths)
 
     return run_guarded(key, spec, _run)
-
-
-def paged_kernel_available() -> bool:
-    """True when this jax/pallas build can run the paged kernel at all."""
-    return HAS_ASYNC_COPY and HAS_SCALAR_PREFETCH

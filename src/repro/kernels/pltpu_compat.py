@@ -1,60 +1,27 @@
-"""Shared Pallas-TPU API compatibility shims + helpers for the kernel modules.
+"""Shared Pallas-TPU helpers for the kernel modules.
 
-jax renamed ``TPUCompilerParams`` -> ``CompilerParams`` across 0.4.x/0.5.x;
-accept either so the kernels run on whatever toolchain the image bakes in.
-The async-copy surface (``make_async_copy`` / ``SemaphoreType`` / the ANY
-memory space) moved around the same releases; the banded/pipelined kernels go
-through the shims below so a toolchain without manual DMA support degrades to
-a clear "not available" signal (the dispatch predicates gate on it) instead
-of an AttributeError mid-trace.
+The manual-DMA surface (``make_async_copy``, DMA semaphores, the HBM memory
+space), the two-slot double-buffer protocol, the
+f32-accumulating MXU contraction, and the one-hot gathers that stand in for
+dynamic gathers Mosaic cannot lower.  The ``repro.analysis`` kernel lints key
+off the names imported from this module.
 """
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+COMPILER_PARAMS = pltpu.CompilerParams
 
-# ---------------------------------------------------------------------------
-# Async-copy (manual DMA) shims — used by the banded conv megakernel and the
-# pipelined strip GEMM, which keep their big operand in HBM and double-buffer
-# row bands / strip chunks into VMEM scratch.
-# ---------------------------------------------------------------------------
+# memory space of a pallas_call input that stays un-blocked in HBM, so the
+# kernel can DMA windows of it manually.  HBM, not ANY: under ANY the
+# compiler may place a small operand in VMEM, where a window narrower than
+# the 128-lane tiling (e.g. a 64-wide head dim) is refused.
+MEM_HBM = pltpu.HBM
 
-# memory space that lets a pallas_call input stay un-blocked (HBM/compiler's
-# choice) so the kernel can DMA slices of it manually
-MEM_ANY = getattr(pltpu, "ANY", None)
-if MEM_ANY is None:  # pre-rename spelling
-    MEM_ANY = getattr(getattr(pltpu, "TPUMemorySpace", None), "ANY", None)
-
-_MAKE_ASYNC_COPY = getattr(pltpu, "make_async_copy", None)
-SEMAPHORE_TYPE = getattr(pltpu, "SemaphoreType", None)
-
-HAS_ASYNC_COPY = (
-    _MAKE_ASYNC_COPY is not None and SEMAPHORE_TYPE is not None
-    and MEM_ANY is not None
-)
-
-# Scalar-prefetched grids (page tables / length vectors delivered to SMEM
-# ahead of the kernel body) — required by the ragged paged-attention kernel,
-# whose DMA source indices come from a runtime page table.
-PREFETCH_GRID_SPEC = getattr(pltpu, "PrefetchScalarGridSpec", None)
-HAS_SCALAR_PREFETCH = PREFETCH_GRID_SPEC is not None
-
-
-def prefetch_grid_spec(*, num_scalar_prefetch, grid, in_specs, out_specs,
-                       scratch_shapes):
-    """Grid spec whose first ``num_scalar_prefetch`` operands are scalar
-    arrays prefetched to SMEM (kernel sees them first; index maps receive
-    them as trailing ref args)."""
-    if PREFETCH_GRID_SPEC is None:
-        raise NotImplementedError(
-            "this jax/pallas build has no pltpu.PrefetchScalarGridSpec; the "
-            "paged-attention kernel is unavailable (its dispatch predicate "
-            "should have gated on pltpu_compat.HAS_SCALAR_PREFETCH)")
-    return PREFETCH_GRID_SPEC(
-        num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-        in_specs=in_specs, out_specs=out_specs,
-        scratch_shapes=scratch_shapes)
+# Reduction-axis chunk of the one-hot gathers: bounds the [chunk, k] one-hot
+# temporary a single gather keeps live in VMEM.
+GATHER_CHUNK = 512
 
 
 def make_async_copy(src_ref, dst_ref, sem_ref):
@@ -62,21 +29,12 @@ def make_async_copy(src_ref, dst_ref, sem_ref):
     spaces, shared by every double-buffered kernel.  Interpret mode executes
     the same descriptor (jax simulates the semaphore), so the DMA path is
     testable on CPU."""
-    if _MAKE_ASYNC_COPY is None:
-        raise NotImplementedError(
-            "this jax/pallas build has no pltpu.make_async_copy; the banded/"
-            "pipelined conv plans are unavailable (their dispatch predicates "
-            "should have gated on pltpu_compat.HAS_ASYNC_COPY)")
-    return _MAKE_ASYNC_COPY(src_ref, dst_ref, sem_ref)
+    return pltpu.make_async_copy(src_ref, dst_ref, sem_ref)
 
 
 def dma_semaphores(n: int):
     """Scratch-shape entry for ``n`` DMA completion semaphores."""
-    if SEMAPHORE_TYPE is None:
-        raise NotImplementedError(
-            "this jax/pallas build has no pltpu.SemaphoreType; manual-DMA "
-            "kernels are unavailable")
-    return SEMAPHORE_TYPE.DMA((n,))
+    return pltpu.SemaphoreType.DMA((n,))
 
 
 def double_buffer_rotate(dma, g, n_chunks, *, gate):
@@ -91,7 +49,6 @@ def double_buffer_rotate(dma, g, n_chunks, *, gate):
     for chunk ``gi`` into scratch slot ``slot``; the descriptor a ``wait``
     reconstructs must be identical to the one ``start`` used.
     """
-    from jax.experimental import pallas as pl
 
     @pl.when(gate)
     def _rotate():
@@ -106,14 +63,64 @@ def double_buffer_rotate(dma, g, n_chunks, *, gate):
         dma(g % 2, g).wait()
 
 
-def dot_f32(a, b, interpret: bool):
+def dot_f32(a, b, interpret: bool, *, trans_a: bool = False):
     """MXU dot with float32 accumulation, shared by every accumulate-flush
-    kernel.  Interpret mode casts the operands up first — XLA:CPU has no
-    bf16xbf16->f32 dot, while the TPU path feeds the MXU native operands."""
+    kernel (``trans_a``: contract ``a``'s leading dim, i.e. ``a.T @ b``).
+    Interpret mode casts the operands up first — XLA:CPU has no
+    bf16xbf16->f32 dot, while the TPU path feeds the MXU native operands.
+    float32 operands contract at full precision, so one-hot gathers of f32
+    data stay exact on the chip."""
     if interpret:
         a = a.astype(jnp.float32)
         b = b.astype(jnp.float32)
-    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+    dims = (((0,) if trans_a else (a.ndim - 1,), (0,)), ((), ()))
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _onehot(ids, c0: int, c1: int, dtype):
+    """[c1 - c0, k] one-hot: entry (r, j) is 1 where ``ids[0, j] == c0 + r``."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (c1 - c0, ids.shape[-1]), 0)
+    return (rows + c0 == ids).astype(dtype)
+
+
+def gather_cols(x_ref, ids, interpret: bool):
+    """``x[:, ids]`` of a 2-D VMEM ref/array ``x`` [rows, n] for a lane
+    vector ``ids`` [1, k] of column ids — as a one-hot MXU contraction, in
+    ``GATHER_CHUNK`` slices of the n axis.  Mosaic lowers no general lane
+    gather; the contraction is exact (each output sums one product with
+    1.0).  Returns float32 [rows, k]."""
+    n = x_ref.shape[1]
+    out = None
+    for c0 in range(0, n, GATHER_CHUNK):
+        c1 = min(n, c0 + GATHER_CHUNK)
+        xs = x_ref[:, c0:c1]
+        part = dot_f32(xs, _onehot(ids, c0, c1, xs.dtype), interpret)
+        out = part if out is None else out + part
+    return out
+
+
+def gather_rows(x_ref, ids, interpret: bool):
+    """``x[ids, :]`` of a 2-D VMEM ref/array ``x`` [n, V] for a lane vector
+    ``ids`` [1, k] of row ids — the sublane twin of :func:`gather_cols`
+    (one-hot contracted over its leading dim).  Returns float32 [k, V]."""
+    n = x_ref.shape[0]
+    out = None
+    for c0 in range(0, n, GATHER_CHUNK):
+        c1 = min(n, c0 + GATHER_CHUNK)
+        xs = x_ref[c0:c1, :]
+        part = dot_f32(_onehot(ids, c0, c1, xs.dtype), xs, interpret,
+                       trans_a=True)
+        out = part if out is None else out + part
+    return out
+
+
+def gather_vmem_bytes(rows: int, k: int, in_bytes: int) -> int:
+    """VMEM temporaries of one :func:`gather_cols`/:func:`gather_rows` call:
+    the int32 compare and cast one-hot of one chunk, plus the f32 result."""
+    return GATHER_CHUNK * k * (4 + in_bytes) + rows * k * 4
 
 
 def should_interpret() -> bool:

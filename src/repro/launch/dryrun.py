@@ -19,6 +19,7 @@ from repro.launch import steps as steps_mod  # noqa: E402
 from repro.models import registry as reg  # noqa: E402
 from repro.optim import AdamWConfig, adamw_init  # noqa: E402
 from repro.roofline.analysis import (  # noqa: E402
+    DRYRUN_DEVICE_KIND,
     Roofline,
     model_flops_for,
 )
@@ -138,6 +139,7 @@ def analyze(cfg, cell, lowered, compiled, mesh, sparsity: float):
         collective_bytes=acc["collective_bytes"],
         model_flops=model_flops_for(cfg, cell, sparsity),
         chips=chips,
+        device_kind=DRYRUN_DEVICE_KIND,
     )
     return {
         "memory_analysis": mem_d,

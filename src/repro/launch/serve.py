@@ -47,6 +47,7 @@ from repro import fault as rfault
 from repro import obs
 from repro.configs import get_config, smoke_config
 from repro.core.pruning import SparsityConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as reg
 from repro.serve import (
     STATUSES,
@@ -59,15 +60,35 @@ from repro.serve import (
 from repro.train.fault import PreemptionGuard, StepWatchdog
 
 
-def build_engine(args) -> Engine:
+def build_engine(args, *, dtype=None) -> Engine:
+    """Engine for ``args`` (``arch``, ``sparsity``, ``smoke``,
+    ``new_tokens``, ``temperature``): random weights from seed 0, linears
+    column-wise compressed at ``sparsity``.  ``dtype`` sets both the compute
+    and the parameter dtype, as a deployment serves them (default: the
+    config's own)."""
     scfg = SparsityConfig(sparsity=args.sparsity, m=None, tile=None,
                           format="compressed_xla" if args.sparsity > 0 else "dense",
                           min_dim=64 if args.smoke else 512)
     cfg = (smoke_config(args.arch) if args.smoke else get_config(args.arch)).with_(
         sparsity=scfg)
+    if dtype:
+        cfg = cfg.with_(dtype=dtype, param_dtype=dtype)
     params, _ = reg.init_params(cfg, jax.random.PRNGKey(0))
     return Engine(cfg, params, ServeConfig(max_new_tokens=args.new_tokens,
                                            temperature=args.temperature))
+
+
+def watchdog_heartbeat(dog: StepWatchdog):
+    """Heartbeat that arms ``dog`` at the first completed scheduler
+    iteration.  That iteration compiles the full-width prefill and decode
+    steps, which can take longer than a wedged-step window; later
+    iterations recompile only for a new packed-prefill length."""
+    def beat():
+        if not dog.started:
+            dog.start()
+        dog.beat()
+
+    return beat
 
 
 def run_static(args) -> None:
@@ -103,11 +124,11 @@ def run_continuous(args) -> None:
     # the watchdog aborts the process if no scheduler iteration completes
     # inside the window (wedged decode step / hung runtime)
     guard = PreemptionGuard().install()
-    dog = StepWatchdog(timeout_s=args.watchdog_s).start()
+    dog = StepWatchdog(timeout_s=args.watchdog_s)
     try:
         completions = sched.run(trace, log_fn=log,
                                 should_drain=lambda: guard.requested,
-                                heartbeat=dog.beat)
+                                heartbeat=watchdog_heartbeat(dog))
     finally:
         dog.stop()
         guard.uninstall()
@@ -199,6 +220,7 @@ def main():
                          "with PATH: enable the obs layer and write a "
                          "Perfetto-loadable Chrome trace to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.paged and not args.continuous:
         raise SystemExit("--paged requires --continuous (the static engine "
                          "uses the contiguous per-batch cache)")

@@ -17,6 +17,7 @@ import jax
 from repro.configs import get_config, smoke_config
 from repro.core.pruning import SparsityConfig
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh, mesh_tp
 from repro.optim import AdamWConfig
 from repro.sharding import ShardingCtx, use_ctx
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config (CPU-friendly)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     scfg = SparsityConfig(sparsity=args.sparsity, m=None, tile=None,
                           format=args.format if args.sparsity > 0 else "dense",

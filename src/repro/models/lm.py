@@ -114,6 +114,17 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return fn
 
 
+def _compute_params(layer_params, cfg: ModelConfig):
+    """Mixed precision: a layer's floating params cast to the compute dtype
+    (the cast's gradient lands on the stored ``param_dtype`` leaves)."""
+    if cfg.param_dtype == cfg.dtype:
+        return layer_params
+    dt = jnp.dtype(cfg.dtype)
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(dt) if jnp.issubdtype(a.dtype, jnp.floating) else a,
+        layer_params)
+
+
 def lm_forward(params, cfg: ModelConfig, batch) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits [B,S,V_padded], aux_loss)."""
     h = _embed_tokens(params, cfg, batch)
@@ -126,7 +137,8 @@ def lm_forward(params, cfg: ModelConfig, batch) -> Tuple[jax.Array, jax.Array]:
     if pat == "attn":
         def body(carry, layer_params):
             hh, = carry
-            hh, a = block_apply(layer_params, cfg, hh, positions=positions,
+            hh, a = block_apply(_compute_params(layer_params, cfg), cfg, hh,
+                                positions=positions,
                                 mrope_positions=mrope_positions)
             return (hh,), a
 

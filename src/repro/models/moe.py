@@ -94,7 +94,6 @@ def moe_apply_shard_map(params, cfg: ModelConfig, x: jax.Array,
     replaces GSPMD's f32 full-buffer dispatch all-reduces (~730 GB/chip/step
     on olmoe train_4k) with one [T_loc, d] bf16 reduction per layer.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding import get_ctx
@@ -166,8 +165,8 @@ def moe_apply_shard_map(params, cfg: ModelConfig, x: jax.Array,
         y = jax.lax.psum(y_loc, "model")  # the ONLY cross-expert collective
         return y.reshape(bl, sl, d), aux
 
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     ew = {kk: params[kk] for kk in params if kk != "router"}
     return fn(x, params["router"], ew)
 

@@ -1,10 +1,11 @@
 from repro.roofline.analysis import (  # noqa: F401
-    HBM_BW,
-    LINK_BW,
-    PEAK_FLOPS,
+    DEVICE_PEAKS,
+    DRYRUN_DEVICE_KIND,
     CollectiveStats,
+    DevicePeaks,
     Roofline,
     model_flops_for,
     parse_collectives,
+    peaks_for,
     shape_bytes,
 )

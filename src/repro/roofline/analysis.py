@@ -1,9 +1,11 @@
 """Roofline analysis from dry-run compiled artifacts.
 
-Three terms per (arch × shape × mesh), hardware = TPU v5e:
-  compute    = HLO_FLOPs_per_chip / peak_FLOP/s        (197 TF bf16 / chip)
-  memory     = HLO_bytes_per_chip / HBM_bw             (819 GB/s / chip)
-  collective = collective_bytes_per_chip / link_bw     (~50 GB/s / ICI link)
+Three terms per (arch × shape × mesh), against the peaks of the target chip
+(:data:`DEVICE_PEAKS`, keyed by ``jax.Device.device_kind``; the dry-run
+targets TPU v5e):
+  compute    = HLO_FLOPs_per_chip / peak_FLOP/s
+  memory     = HLO_bytes_per_chip / HBM_bw
+  collective = collective_bytes_per_chip / link_bw
 
 cost_analysis() is computed on the post-SPMD per-device module, so flops /
 bytes are already per-chip.  Collective bytes are NOT in cost_analysis —
@@ -18,9 +20,34 @@ import json
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12      # bf16 per chip
-HBM_BW = 819e9           # bytes/s per chip
-LINK_BW = 50e9           # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float         # dense bf16 FLOP/s per chip
+    hbm_bw: float        # HBM bytes/s per chip
+    link_bw: float       # bytes/s per inter-chip (ICI) link
+
+
+#: Published per-chip peaks by ``device_kind``.  TPU v5e (Google Cloud
+#: documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+#: of inter-chip interconnect over four links (50 GB/s each).
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+#: the chip the dry-run's per-chip roofline models
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; a kind without published peaks here is an
+    error, never a silent default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks recorded for device kind {device_kind!r}; "
+                       f"known: {sorted(DEVICE_PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -106,18 +133,23 @@ class Roofline:
     collective_bytes: float      # per chip
     model_flops: float           # 6*N*D global
     chips: int
+    device_kind: str             # key of DEVICE_PEAKS
+
+    @property
+    def peaks(self) -> DevicePeaks:
+        return peaks_for(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / LINK_BW
+        return self.collective_bytes / self.peaks.link_bw
 
     @property
     def bottleneck(self) -> str:
@@ -141,7 +173,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """Fraction of the step's bound time spent at the compute roofline if
         only MODEL_FLOPS were executed — the 'score' we hillclimb."""
-        ideal = self.model_flops / self.chips / PEAK_FLOPS
+        ideal = self.model_flops / self.chips / self.peaks.flops
         return ideal / self.t_bound if self.t_bound else 0.0
 
     def to_dict(self) -> Dict:
@@ -151,6 +183,7 @@ class Roofline:
             "collective_bytes_per_chip": self.collective_bytes,
             "model_flops": self.model_flops,
             "chips": self.chips,
+            "device_kind": self.device_kind,
             "t_compute_s": self.t_compute,
             "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective,

@@ -68,6 +68,19 @@ def get_ctx() -> Optional[ShardingCtx]:
     return _CURRENT
 
 
+def gspmd_devices() -> int:
+    """Number of devices GSPMD would partition an op traced here over: the
+    installed context's mesh, less the axes a ``jax.shard_map`` body has made
+    manual (1 with no context installed, and inside a body manual over every
+    axis, where the op is per-shard)."""
+    ctx = _CURRENT
+    if ctx is None:
+        return 1
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    return math.prod(size for ax, size in ctx.mesh.shape.items()
+                     if ax not in manual)
+
+
 @contextlib.contextmanager
 def use_ctx(ctx: Optional[ShardingCtx]):
     prev = get_ctx()

@@ -22,7 +22,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def ring_allgather_matmul_local(x_shard: jax.Array, w_full: jax.Array,
@@ -56,11 +55,11 @@ def ring_allgather_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
                           axis: str = "model") -> jax.Array:
     """y = x @ w with x's feature dim sharded over `axis`, overlapping the
     gather with partial matmuls. x: [B, d_in]; w: [d_in, d_out]."""
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_allgather_matmul_local, axis_name=axis),
         mesh=mesh,
         in_specs=(P(None, axis), P(None, None)),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, w)

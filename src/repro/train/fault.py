@@ -80,6 +80,10 @@ class StepWatchdog:
         self._thread.start()
         return self
 
+    @property
+    def started(self) -> bool:
+        return self._thread is not None
+
     def beat(self):
         self._last = time.monotonic()
 

@@ -38,7 +38,10 @@ from repro.kernels.conv_gemm import (
     conv2d_two_kernel,
     conv2d_two_kernel_pipelined,
 )
+from repro.kernels.conv_gemm.kernel import _conv_step_vmem_bytes
 from repro.kernels.im2col_pack import im2col_pack_ref, out_size
+from repro.kernels.im2col_pack.kernel import window_plan
+from repro.kernels.pltpu_compat import ceil_to
 from repro.models import vision
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -264,7 +267,7 @@ class TestPlanLadder:
         hb = spec.geom("hb")
         # w chosen so hb*v does not divide wo: bands cross an output-row
         # boundary and the window carries the full stride+halo row count
-        kw = dict(c=320, h=640, w=1800, o=256, k_kept=1440, tile=128)
+        kw = dict(c=64, h=640, w=600, o=256, k_kept=288, tile=128)
         f32 = dispatch.conv_key(kw["c"], kw["h"], kw["w"], kw["o"], 3, 3, 1,
                                 1, kw["k_kept"], kw["tile"], dtype="float32")
         bf16 = dispatch.conv_key(kw["c"], kw["h"], kw["w"], kw["o"], 3, 3, 1,
@@ -274,10 +277,20 @@ class TestPlanLadder:
         assert not spec.feasible(f32)[0] and spec.feasible(bf16)[0]
         ho = out_size(kw["h"], 3, 1, 1)
         wo = out_size(kw["w"], 3, 1, 1)
+        v = spec.geom("v")
         _, rows = band_plan(b=1, h=kw["h"], kh=3, stride=1, pad=1, ho=ho,
-                            wo=wo, v=spec.geom("v"), hb=hb)
-        one_band = kw["c"] * rows * kw["w"] * 4
-        assert spec.vmem_bytes(f32) > 2 * one_band
+                            wo=wo, v=v, hb=hb)
+        # one band buffer as the kernel allocates it: an aligned row window
+        # over the lane-padded map
+        win_rows, _ = window_plan(rows, kw["h"])
+        w_pad = ceil_to(kw["w"], 128)
+        one_band = kw["c"] * win_rows * w_pad * 4
+        assert spec.vmem_bytes(f32) >= 2 * one_band
+        # beyond the per-step working set it counts exactly two buffers
+        step = _conv_step_vmem_bytes(kw["c"], w_pad, win_rows, 9, v,
+                                     min(spec.geom("bk"), kw["k_kept"]),
+                                     kw["tile"], 4)
+        assert spec.vmem_bytes(f32) - step == 2 * one_band
 
     def test_banded_geometry_cross_process_deterministic(self, db):
         """A frozen DB naming a banded geometry variant reproduces the

@@ -33,7 +33,6 @@ from repro.kernels.conv_gemm import (
     conv2d_cnhw_ref,
     conv2d_sparse,
 )
-from repro.kernels.pltpu_compat import HAS_ASYNC_COPY
 from repro.models import vision
 
 
@@ -54,7 +53,6 @@ RUNGS = [
     "im2col_sparse_pallas",
     "im2col_sparse_xla",
 ]
-DMA_RUNGS = {"fused_banded_pallas", "two_kernel_pipelined"}
 
 
 def _conv_problem(c, b, h, w, o, k, stride, pad, dtype=jnp.float32, seed=0):
@@ -106,8 +104,6 @@ class TestConvVJPLadder:
     )
     def test_grad_matches_dense_reference(self, db, impl, c, b, h, w, o, k,
                                           stride, pad):
-        if impl in DMA_RUNGS and not HAS_ASYNC_COPY:
-            pytest.skip("pallas build has no make_async_copy")
         x, values, idx, wm, cot = _conv_problem(c, b, h, w, o, k, stride, pad)
 
         def loss(x, values):
@@ -144,8 +140,6 @@ class TestConvVJPLadder:
     def test_env_forced_rung_grad(self, db, monkeypatch):
         # REPRO_DISPATCH_FORCE pins the forward rung; the backward must still
         # be the shared VJP and match the dense reference
-        if not HAS_ASYNC_COPY:
-            pytest.skip("pallas build has no make_async_copy")
         monkeypatch.setenv("REPRO_DISPATCH_FORCE", "fused_banded_pallas")
         x, values, idx, wm, cot = _conv_problem(8, 2, 10, 10, 16, 3, 1, 1)
 

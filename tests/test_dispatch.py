@@ -111,6 +111,47 @@ class TestRegistry:
         ok, reason = REGISTRY.get("linear", "compressed_pallas").feasible(odd)
         assert not ok
 
+    def test_sharded_mesh_keys_refuse_pallas(self):
+        # GSPMD cannot partition a Mosaic kernel: an op traced under a
+        # multi-device sharding context gets a mesh-tagged key on which every
+        # Pallas candidate is infeasible; single-device tokens are unchanged,
+        # and so are keys traced inside a shard_map body manual over every
+        # mesh axis (there the op is per-shard)
+        import types
+
+        from jax.sharding import PartitionSpec as P
+
+        from repro.launch.mesh import make_mesh
+        from repro.sharding import ShardingCtx, use_ctx
+
+        plain = linear_key(batch=8, d_in=512, d_out=512, k_kept=256, tile=128)
+        ctx = ShardingCtx(mesh=types.SimpleNamespace(
+            shape={"data": 1, "model": 4}))
+        in_body = []
+
+        def body(x):
+            in_body.append(linear_key(batch=8, d_in=512, d_out=512,
+                                      k_kept=256, tile=128))
+            return x
+
+        with use_ctx(ctx):
+            sharded = linear_key(batch=8, d_in=512, d_out=512, k_kept=256,
+                                 tile=128)
+            conv = dispatch.conv_key(16, 8, 8, 16, 3, 3, 1, 1, 72, 16)
+            paged = dispatch.paged_attn_key(8, 8, 2, 64, 256, page_size=16)
+            jax.jit(jax.shard_map(
+                body, mesh=make_mesh((1, 1), ("data", "model")),
+                in_specs=P(), out_specs=P()))(jnp.zeros(8))
+        assert "mesh" not in plain.token and sharded.get("mesh") == 4
+        assert in_body[0].token == plain.token
+        for key, op in ((sharded, "linear"), (conv, "conv"),
+                        (paged, "paged_attn")):
+            for spec in REGISTRY.candidates(op):
+                ok, reason = spec.feasible(key)
+                if spec.backend == "pallas":
+                    assert not ok and "partitioned" in reason, spec.name
+        assert REGISTRY.get("linear", "compressed_pallas").feasible(plain)[0]
+
     def test_infeasible_key_still_dispatches(self, db):
         # every predicate failing degrades to smallest-footprint, not a crash
         odd = OpKey(op="linear", batch=8, d_in=64, d_out=60, k_kept=30, tile=7)

@@ -32,8 +32,9 @@ class TestDistributed:
     def test_ring_collective_matmul(self):
         out = run_with_devices("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.sharding.collective_matmul import ring_allgather_matmul
-            mesh = jax.make_mesh((8,), ("model",))
+            mesh = make_mesh((8,), ("model",))
             x = jax.random.normal(jax.random.PRNGKey(0), (16, 64))
             w = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
             with mesh:
@@ -52,12 +53,12 @@ class TestDistributed:
             from repro.configs import smoke_config
             from repro.core.pruning import SparsityConfig
             from repro.launch import steps as steps_mod
-            from repro.launch.mesh import mesh_tp
+            from repro.launch.mesh import make_mesh, mesh_tp
             from repro.models import registry as reg
             from repro.optim import AdamWConfig, adamw_init
             from repro.sharding import ShardingCtx, use_ctx
 
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             scfg = SparsityConfig(0.5, m=None, tile=None, format="compressed_xla",
                                   min_dim=32, shard_local_reduce=True, reduce_groups=4)
             cfg = smoke_config("qwen2-7b").with_(
@@ -88,16 +89,16 @@ class TestDistributed:
             import functools
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from repro.launch.mesh import make_mesh
             from repro.optim.grad_compress import crosspod_psum_compressed
-            mesh = jax.make_mesh((4, 2), ("pod", "data"))
+            mesh = make_mesh((4, 2), ("pod", "data"))
             g = jax.random.normal(jax.random.PRNGKey(0), (4, 256))
             e = jnp.zeros((4, 256))
 
-            f = shard_map(
+            f = jax.shard_map(
                 functools.partial(crosspod_psum_compressed, axis="pod"),
                 mesh=mesh, in_specs=(P("pod", None), P("pod", None)),
-                out_specs=(P("pod", None), P("pod", None)), check_rep=False)
+                out_specs=(P("pod", None), P("pod", None)), check_vma=False)
             with mesh:
                 reduced, err = f(g, e)
             # every pod-shard of `reduced` equals the true sum up to int8 error
@@ -119,9 +120,10 @@ def test_shard_map_moe_matches_auto():
         from repro.configs import smoke_config
         from repro.models.moe import moe_apply, moe_apply_shard_map, moe_init
         from repro.core.sparse_linear import unbox_tree
+        from repro.launch.mesh import make_mesh
         from repro.sharding import ShardingCtx, use_ctx
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = smoke_config("olmoe-1b-7b").with_(
             d_model=64, d_ff=96, n_experts=8, top_k=2, capacity_factor=8.0,
             tp=4, dp=2, moe_impl="shard_map")
@@ -138,3 +140,43 @@ def test_shard_map_moe_matches_auto():
         print("MOE_MANUAL_OK")
     """)
     assert "MOE_MANUAL_OK" in out
+
+
+_TRAIN_PHASE = """
+    import os, sys
+    sys.path.insert(0, {repo!r})
+    if {force!r}:
+        os.environ["REPRO_DISPATCH_FORCE"] = {force!r}
+    import jax
+    import chip_smoke as cs
+    from repro import obs
+    from repro.configs import smoke_config
+
+    obs.set_enabled(True)
+    cfg = cs.train_config(
+        smoke_config("qwen2-7b").with_(
+            n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, head_dim=16,
+            d_ff=128, vocab_size=256),
+        min_dim=32, tile=16)
+    chk = cs.Checks()
+    cs.train_phase(chk, cfg, jax.devices()[:4], batch=4, seq=32,
+                   expect_backend={backend!r})
+    assert not chk.failed, chk.failed
+    print("TRAIN_PHASE_OK")
+"""
+
+
+@pytest.mark.parametrize("force,backend", [
+    ("", "xla"),                    # dispatched as on the CPU
+    ("compressed_pallas", "pallas"),  # interpret mode, inside shard_map
+])
+def test_chip_smoke_train_phase_rehearsal(force, backend):
+    """``chip_smoke.py --chips 4`` at smoke widths on 4 virtual CPU devices:
+    the sharded REDUCE-format train step on a (data=1, model=4) mesh runs
+    3 steps, its loss is finite and falls, and step 0's loss and logits
+    match a bf16 forward of the same parameters on device 0 alone.  The
+    compressed linears run per shard in ``jax.shard_map``, where the forced
+    Pallas candidate's key carries no mesh and stays feasible."""
+    out = run_with_devices(_TRAIN_PHASE.format(repo=str(REPO), force=force,
+                                               backend=backend), n=4)
+    assert "TRAIN_PHASE_OK" in out

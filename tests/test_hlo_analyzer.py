@@ -74,3 +74,17 @@ class TestHloAnalyzer:
         # boundary traffic should be ~ read + write of the array, not 4 passes
         nbytes = 1024 * 1024 * 4
         assert nbytes * 1.5 <= got["bytes"] <= nbytes * 6
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.roofline.analysis import DEVICE_PEAKS, Roofline, peaks_for
+
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    rl = Roofline(flops=197e12, hlo_bytes=819e9, collective_bytes=0.0,
+                  model_flops=197e12, chips=1, device_kind="TPU v5 lite")
+    assert rl.t_compute == pytest.approx(1.0)
+    assert rl.t_memory == pytest.approx(1.0)
+    with pytest.raises(KeyError, match="no peaks recorded"):
+        peaks_for("TPU v9 imaginary")
+    assert "TPU v9 imaginary" not in DEVICE_PEAKS

@@ -24,15 +24,9 @@ from repro.kernels.flash_attn import (
     paged_attention,
     paged_attention_pallas,
     paged_attention_ref,
-    paged_kernel_available,
 )
 
 REPO = Path(__file__).resolve().parent.parent
-
-pytestmark = pytest.mark.skipif(
-    not paged_kernel_available(),
-    reason="pallas build lacks async-copy or scalar-prefetch support")
-
 
 def _problem(b=3, sq=1, h=4, kv=2, d=16, n_pages=4, page_size=8,
              lengths=None, seed=0, dtype=jnp.float32, shuffle=False):
