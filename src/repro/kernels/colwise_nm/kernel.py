@@ -40,6 +40,7 @@ from repro.kernels.pltpu_compat import (
     gather_cols,
     gather_rows,
     gather_vmem_bytes,
+    kernel_tag,
     make_async_copy,
 )
 
@@ -132,6 +133,7 @@ def colwise_nm_matmul_pallas(
         compiler_params=_COMPILER_PARAMS(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        metadata=kernel_tag("colwise_nm"),
         interpret=interpret,
     )(x, idx, values)
     return out[:B]
@@ -202,6 +204,7 @@ def colwise_nm_matmul_strips_pallas(
         compiler_params=_COMPILER_PARAMS(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        metadata=kernel_tag("colwise_nm"),
         interpret=interpret,
     )(strips, idx, values)
     return out
@@ -327,6 +330,7 @@ def colwise_nm_matmul_strips_pipelined_pallas(
             # chunk g's steps complete before chunk g+1's begin
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
+        metadata=kernel_tag("colwise_nm"),
         interpret=interpret,
     )(strips, idx, values)
     return out

@@ -41,6 +41,7 @@ from repro.kernels.pltpu_compat import (
     double_buffer_rotate,
     gather_rows,
     gather_vmem_bytes,
+    kernel_tag,
     make_async_copy,
 )
 
@@ -180,6 +181,7 @@ def conv2d_fused_pallas(
             # reused by every later tile and chunk of that strip
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
+        metadata=kernel_tag("conv_fused"),
         interpret=interpret,
     )(pad_rows(x, bh_pad), idx, values)
     return out
@@ -360,6 +362,7 @@ def conv2d_fused_banded_pallas(
             # band g's steps complete before band g+1's begin
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
+        metadata=kernel_tag("conv_fused_banded"),
         interpret=interpret,
     )(pad_rows(x, bh_pad, lanes=128), idx, values)
     return out

@@ -21,7 +21,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.pltpu_compat import COMPILER_PARAMS as _COMPILER_PARAMS
-from repro.kernels.pltpu_compat import ceil_to, dot_f32
+from repro.kernels.pltpu_compat import ceil_to, dot_f32, kernel_tag
 
 NEG = -1e30
 
@@ -106,6 +106,7 @@ def flash_attention_pallas(
         compiler_params=_COMPILER_PARAMS(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        metadata=kernel_tag("flash_attn"),
         interpret=interpret,
     )(q, k, v)
     return out[:, :sq]

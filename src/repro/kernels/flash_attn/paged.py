@@ -38,6 +38,7 @@ from repro.kernels.pltpu_compat import (
     COMPILER_PARAMS as _COMPILER_PARAMS,
     ceil_to,
     dot_f32,
+    kernel_tag,
     should_interpret,
 )
 
@@ -180,6 +181,7 @@ def paged_attention_pallas(
         compiler_params=_COMPILER_PARAMS(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        metadata=kernel_tag("paged_attn"),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
       q, k_new, v_new, k_pages, v_pages)

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.pltpu_compat import COMPILER_PARAMS as _COMPILER_PARAMS
-from repro.kernels.pltpu_compat import ceil_to, dot_f32
+from repro.kernels.pltpu_compat import ceil_to, dot_f32, kernel_tag
 
 from repro.kernels.im2col_pack.ref import out_size
 
@@ -269,6 +269,7 @@ def im2col_pack_pallas(
         compiler_params=_COMPILER_PARAMS(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
+        metadata=kernel_tag("im2col_pack"),
         interpret=interpret,
     )(pad_rows(x, bh_pad))
     return out

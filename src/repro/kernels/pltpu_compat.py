@@ -19,6 +19,24 @@ COMPILER_PARAMS = pltpu.CompilerParams
 # the 128-lane tiling (e.g. a 64-wide head dim) is refused.
 MEM_HBM = pltpu.HBM
 
+# Kernel families.  Every ``pallas_call`` passes ``metadata=kernel_tag(f)``
+# for its family ``f``; the compiled custom call carries it as
+# ``frontend_attributes={kernel_metadata={"kernel": "<f>"}}`` in its HLO
+# text, so a profiler trace names the kernel whatever the jitted wrapper
+# around it is called (inside a scanned layer every kernel is a
+# ``closed_call``).
+KERNEL_FAMILIES = ("colwise_nm", "conv_fused", "conv_fused_banded",
+                   "im2col_pack", "paged_attn", "flash_attn")
+
+
+def kernel_tag(family: str) -> dict:
+    """The ``pallas_call`` metadata naming ``family``."""
+    if family not in KERNEL_FAMILIES:
+        raise ValueError(f"unknown kernel family {family!r}; add it to "
+                         f"KERNEL_FAMILIES")
+    return {"kernel": family}
+
+
 # Reduction-axis chunk of the one-hot gathers: bounds the [chunk, k] one-hot
 # temporary a single gather keeps live in VMEM.
 GATHER_CHUNK = 512

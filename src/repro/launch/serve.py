@@ -16,9 +16,10 @@ spans, per-request TTFT/TPOT metrics) loadable in Perfetto:
 
 ``--paged`` switches the continuous scheduler onto the paged-KV memory tier
 (``repro.serve.kv_pages``): block-granular admission, packed padding-free
-prefill, and page-occupancy gauges in the summary (and in the ``--trace``
-metrics snapshot).  ``--page-size`` pins the page size; omitted, dispatch
-races the registered page-size geometries for the serving shape:
+prefill, and page-occupancy gauges in the summary (and, as
+``sched.page_stats``, in the ``--trace`` file's metadata).
+``--page-size`` pins the page size; omitted, dispatch races the registered
+page-size geometries for the serving shape:
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m --smoke \
         --continuous --paged --page-size 8 --requests 12 --slots 4
@@ -39,6 +40,7 @@ queued ones flush as cancelled.  A scheduler-iteration watchdog
 from __future__ import annotations
 
 import argparse
+from typing import Optional
 
 import jax
 import numpy as np
@@ -104,7 +106,7 @@ def run_static(args) -> None:
         print(f"  seq{i}: {row[:16].tolist()}")
 
 
-def run_continuous(args) -> None:
+def run_continuous(args) -> Scheduler:
     if args.requests < 1:
         raise SystemExit("--continuous needs --requests >= 1")
     eng = build_engine(args)
@@ -162,10 +164,17 @@ def run_continuous(args) -> None:
               f"fragmentation {ps['page_fragmentation']:.2f}")
     for c in completions[:2]:
         print(f"  uid={c.uid}: {c.tokens[:16].tolist()}")
+    return sched
 
 
-def _finish_trace(path: str) -> None:
-    n = obs.dump_chrome_trace(path, metadata={"metrics": obs.snapshot()})
+def _finish_trace(path: str, sched: Optional[Scheduler] = None) -> None:
+    """Dump the trace; a continuous run's metadata also carries the
+    scheduler's own stats and page occupancy."""
+    meta = {"metrics": obs.snapshot()}
+    if sched is not None:
+        meta["sched.stats"] = sched.stats
+        meta["sched.page_stats"] = sched.page_stats
+    n = obs.dump_chrome_trace(path, metadata=meta)
     print(f"trace: wrote {n} events to {path} (load in ui.perfetto.dev)")
 
 
@@ -232,9 +241,10 @@ def main():
         obs.set_enabled(True)
     if args.faults:
         rfault.install(args.faults, seed=args.faults_seed)
+    sched = None
     try:
         if args.continuous:
-            run_continuous(args)
+            sched = run_continuous(args)
         else:
             run_static(args)
     finally:
@@ -243,7 +253,7 @@ def main():
                   f"of probes {dict(rfault.plan().probes)}")
             rfault.uninstall()
         if trace_path:
-            _finish_trace(trace_path)
+            _finish_trace(trace_path, sched)
 
 
 if __name__ == "__main__":

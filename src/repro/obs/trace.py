@@ -15,6 +15,12 @@ Design constraints (the reason this module exists instead of printf):
     as ``dispatch.phase_scope``) tracks the open-span path, so events carry
     their nesting depth/parent without threading a span object through call
     signatures; spans close correctly under exceptions (``finally``).
+  * **Profiler bridge.** While a ``jax.profiler`` session is collecting
+    host events, every span also opens a ``jax.profiler.TraceAnnotation``
+    of its name and args, whether or not recording is on, so the program's
+    spans land in the profiler's host plane on the same clock as the device
+    ops.  With no session active a span pays one ``is_enabled`` check.
+    Instants stay ring-only.
   * **Standard export.** :func:`dump_chrome_trace` writes the Chrome
     trace-event JSON format (``{"traceEvents": [...]}``) loadable in
     Perfetto / ``chrome://tracing``; spans are B/E duration-event pairs,
@@ -34,6 +40,8 @@ import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 __all__ = [
     "enabled", "set_enabled", "configure", "span", "instant", "events",
@@ -67,6 +75,9 @@ def _env_ring() -> int:
 
 # module-global fast path: instrumentation points read one bool
 _ENABLED: bool = _env_enabled()
+
+# is a profiler session collecting host events? (one TraceMe level check)
+_profiling = _Annotation.is_enabled
 
 
 def enabled() -> bool:
@@ -146,9 +157,10 @@ def _event(ph: str, name: str, cat: str, args: Optional[Dict] = None,
 
 class _Span:
     """Recording span: emits a B event on enter, an E event on exit (also on
-    exceptions), and maintains the ambient nesting stack."""
+    exceptions), and maintains the ambient nesting stack; also annotates the
+    profiler's timeline while a session is active."""
 
-    __slots__ = ("name", "cat", "args", "_token", "_extra")
+    __slots__ = ("name", "cat", "args", "_token", "_extra", "_ann")
 
     def __init__(self, name: str, cat: str, args: Dict):
         self.name = name
@@ -156,10 +168,13 @@ class _Span:
         self.args = args
         self._token = None
         self._extra: Dict = {}
+        self._ann = None
 
     def set(self, **kwargs) -> "_Span":
         """Attach result args known only at span end (merged into E)."""
         self._extra.update(kwargs)
+        if self._ann is not None:
+            self._ann.set_metadata(**kwargs)
         return self
 
     def __enter__(self) -> "_Span":
@@ -168,9 +183,15 @@ class _Span:
         args["depth"] = len(stack)
         self._token = _STACK.set(stack + (self.name,))
         _RING.append(_event("B", self.name, self.cat, args))
+        if _profiling():
+            self._ann = _Annotation(self.name, **self.args)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         if self._token is not None:
             _STACK.reset(self._token)
         args = dict(self._extra)
@@ -180,8 +201,31 @@ class _Span:
         return False  # never swallow
 
 
+class _ProfilerSpan:
+    """Span handed out while recording is off but a profiler session is
+    active: a ``TraceAnnotation`` only, nothing in the ring buffer."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, args: Dict):
+        self._ann = _Annotation(name, **args)
+
+    def set(self, **kwargs) -> "_ProfilerSpan":
+        self._ann.set_metadata(**kwargs)
+        return self
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
 class _NullSpan:
-    """No-op span handed out while recording is off (one shared instance)."""
+    """No-op span handed out while recording is off and no profiler session
+    is active (one shared instance)."""
 
     __slots__ = ()
 
@@ -201,13 +245,17 @@ _NULL_SPAN = _NullSpan()
 def span(name: str, cat: str = "repro", **args):
     """Context manager recording a B/E duration pair around its body.
 
-    Zero-cost when disabled: returns a shared no-op object, allocates
-    nothing.  ``with span("dispatch.resolve", token=...) as s: ...;
+    With recording off it records nothing; while a ``jax.profiler``
+    session is active it still annotates the profiler's timeline, and
+    otherwise returns a shared no-op object and allocates nothing.
+    ``with span("dispatch.resolve", token=...) as s: ...;
     s.set(impl=...)`` attaches end-of-span result args.
     """
-    if not _ENABLED:
-        return _NULL_SPAN
-    return _Span(name, cat, args)
+    if _ENABLED:
+        return _Span(name, cat, args)
+    if _profiling():
+        return _ProfilerSpan(name, args)
+    return _NULL_SPAN
 
 
 def instant(name: str, cat: str = "repro", **args) -> None:

@@ -47,14 +47,20 @@ class ServeConfig:
     dispatch_seq_hint: int = 128
 
 
-def _phased(fn, phase: str):
-    """Wrap a step fn so its jit trace runs inside a dispatch phase scope."""
+def _phased(fn, phase: str, entry: Optional[str] = None):
+    """Wrap a step fn so its jit trace runs inside a dispatch phase scope,
+    under an ``engine.trace`` span naming the entry point (``phase`` when
+    not given).  The body runs only when JAX traces the step, so the span
+    marks each retrace (and how long tracing took) and costs nothing on a
+    cached call."""
+    entry = entry or phase
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
         from repro import dispatch as _dispatch
 
-        with _dispatch.phase_scope(phase):
+        with _ot.span("engine.trace", entry=entry), \
+                _dispatch.phase_scope(phase):
             return fn(*args, **kwargs)
 
     return wrapped
@@ -143,7 +149,8 @@ class Engine:
             # pool cache, and a full-extent slice (n_slots == 1) can alias
             # the pool's own buffer — donating it would delete the pool
             self._prefill_chunk = jax.jit(
-                _phased(reg.prefill_chunk_fn(self.cfg), "prefill"),
+                _phased(reg.prefill_chunk_fn(self.cfg), "prefill",
+                        "prefill_chunk"),
                 static_argnums=(4,))
         return self._prefill_chunk(self.params, cache, jnp.asarray(tokens),
                                    jnp.asarray(start, jnp.int32),
@@ -162,10 +169,12 @@ class Engine:
         if self._paged_page_size == page_size:
             return
         self._paged_decode = jax.jit(
-            _phased(reg.paged_decode_fn(self.cfg, page_size), "decode"),
+            _phased(reg.paged_decode_fn(self.cfg, page_size), "decode",
+                    "paged_decode"),
             donate_argnums=(1,))
         self._prefill_packed = jax.jit(
-            _phased(reg.prefill_packed_fn(self.cfg, page_size), "prefill"),
+            _phased(reg.prefill_packed_fn(self.cfg, page_size), "prefill",
+                    "prefill_packed"),
             donate_argnums=(1,))
         self._paged_page_size = page_size
 

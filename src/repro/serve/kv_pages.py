@@ -33,12 +33,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from repro import fault as _fault
-from repro.obs import metrics as _om
 from repro.obs import trace as _ot
-
-_G_PAGES_ACTIVE = _om.gauge("serve.pages_active")
-_G_PAGES_FREE = _om.gauge("serve.pages_free")
-_G_PAGE_FRAG = _om.gauge("serve.page_fragmentation")
 
 
 class PageError(RuntimeError):
@@ -85,7 +80,6 @@ class PagePool:
         self._tables: Dict[int, PageTable] = {}
         self.peak_pages = 0
         self.peak_seqs = 0
-        self._set_gauges()
 
     # -- properties ---------------------------------------------------------
 
@@ -155,7 +149,6 @@ class PagePool:
         self.peak_pages = max(self.peak_pages, self.n_mapped)
         self.peak_seqs = max(self.peak_seqs, len(self._tables))
         self.check_invariants()
-        self._set_gauges()
         _ot.instant("serve.page_alloc", seq=seq_id, pages=need,
                     rows=int(n_rows), free=len(self._free),
                     request=request_id)
@@ -179,7 +172,6 @@ class PagePool:
         table._capacity = len(table.pages) * self.page_size
         self.peak_pages = max(self.peak_pages, self.n_mapped)
         self.check_invariants()
-        self._set_gauges()
         _ot.instant("serve.page_alloc", seq=seq_id, pages=need,
                     rows=int(n_rows), free=len(self._free), grow=True)
         return table
@@ -214,7 +206,6 @@ class PagePool:
         table._capacity = keep * self.page_size
         self._free.extend(reversed(released))
         self.check_invariants()
-        self._set_gauges()
         _ot.instant("serve.page_release", seq=seq_id, pages=n_rel,
                     free=len(self._free))
         return n_rel
@@ -227,7 +218,6 @@ class PagePool:
         # Reverse so re-allocation hands the same pages back in order.
         self._free.extend(reversed(table.pages))
         self.check_invariants()
-        self._set_gauges()
         _ot.instant("serve.page_free", seq=seq_id, pages=len(table.pages),
                     free=len(self._free))
 
@@ -298,11 +288,6 @@ class PagePool:
         if table is None:
             raise PageError(f"seq {seq_id} holds no page table")
         return table
-
-    def _set_gauges(self) -> None:
-        _G_PAGES_ACTIVE.set(self.n_mapped)
-        _G_PAGES_FREE.set(len(self._free))
-        _G_PAGE_FRAG.set(self.fragmentation())
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return (f"PagePool(n_pages={self.n_pages}, page_size={self.page_size},"

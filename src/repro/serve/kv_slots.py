@@ -20,14 +20,6 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from repro.obs import metrics as _om
-
-# process-global occupancy gauges (no-ops while obs is off): last-write-wins,
-# updated on every alloc/free so a trace-side metrics snapshot always shows
-# the live slot occupancy of the most recently active pool
-_G_POOL_ACTIVE = _om.gauge("serve.pool_active")
-_G_POOL_FREE = _om.gauge("serve.pool_free")
-
 
 class SlotError(RuntimeError):
     """A slot-pool invariant was violated (double-assign, double-free, leak)."""
@@ -89,8 +81,6 @@ class SlotPool:
         slot = Slot(index=index, request_id=request_id, pos=0)
         self._active[index] = slot
         self.check_invariants()
-        _G_POOL_ACTIVE.set(len(self._active))
-        _G_POOL_FREE.set(len(self._free))
         return slot
 
     def free(self, index: int) -> None:
@@ -102,8 +92,6 @@ class SlotPool:
             raise SlotError(f"slot {index} double-freed")
         self._free.append(index)
         self.check_invariants()
-        _G_POOL_ACTIVE.set(len(self._active))
-        _G_POOL_FREE.set(len(self._free))
 
     def advance(self, index: int, by: int = 1) -> int:
         """Advance a slot's written-position counter; bounds-checked against

@@ -53,20 +53,6 @@ from repro.serve.engine import Engine
 from repro.serve.kv_pages import PageError, PagePool, pack_prompts
 from repro.serve.kv_slots import SlotPool
 
-# Global-registry mirrors (no-ops while obs is off): the process-wide view a
-# trace file carries, alongside each Scheduler's private always-on registry
-# that backs its ``stats`` property.
-_G_STEPS = _om.counter("serve.decode_steps")
-_G_DECODE_S = _om.counter("serve.decode_s")
-_G_TOKENS = _om.counter("serve.generated_tokens")
-_G_COMPLETED = _om.counter("serve.completed_requests")
-_G_PREEMPTIONS = _om.counter("serve.preemptions")
-_G_QUEUE = _om.gauge("serve.queue_depth")
-_G_ACTIVE = _om.gauge("serve.slots_active")
-_G_TTFT = _om.histogram("serve.ttft_s")
-_G_TPOT = _om.histogram("serve.tpot_s")
-_G_LATENCY = _om.histogram("serve.latency_s")
-
 #: Terminal request statuses (every Completion carries exactly one).
 STATUSES = ("ok", "timeout", "cancelled", "failed", "preempted")
 
@@ -435,7 +421,6 @@ class Scheduler:
             """Shared retire bookkeeping: counters, histograms (ok only, so
             cancellations don't skew latency percentiles), obs events."""
             c_done.inc()
-            _G_COMPLETED.inc()
             m.counter(f"retired_{comp.status}").inc()
             self._cancelled.discard(comp.uid)  # consume the cancel request
             if comp.status == "ok":
@@ -443,9 +428,6 @@ class Scheduler:
                 h_ttft.observe(comp.ttft_s)
                 h_tpot.observe(tpot)
                 h_lat.observe(comp.latency_s)
-                _G_TTFT.observe(comp.ttft_s)
-                _G_TPOT.observe(tpot)
-                _G_LATENCY.observe(comp.latency_s)
             _ot.instant("serve.retire", uid=comp.uid, status=comp.status,
                         generated=comp.n_generated,
                         ttft_s=round(comp.ttft_s, 6),
@@ -511,7 +493,6 @@ class Scheduler:
             restored._restores = getattr(base, "_restores", 0) + 1
             queue.push_front(restored)
             c_preempt.inc()
-            _G_PREEMPTIONS.inc()
             _ot.instant("serve.preempt", uid=base.uid, slot=idx,
                         generated=int(gen.shape[0]),
                         restores=restored._restores, reason=reason[:120])
@@ -558,17 +539,18 @@ class Scheduler:
                 def _expired(r: Request) -> bool:
                     return r.deadline_s is not None and now - t0 > r.deadline_s
 
-                for r in queue.take(
-                        lambda r: r.uid in self._cancelled or _expired(r)):
-                    status = ("cancelled" if r.uid in self._cancelled
-                              else "timeout")
-                    done_now.append(finish_queued(r, status))
-                for idx in sorted(inflight):
-                    st = inflight[idx]
-                    if st.req.uid in self._cancelled:
-                        done_now.append(retire(idx, "cancelled"))
-                    elif _expired(st.req):
-                        done_now.append(retire(idx, "timeout"))
+                with _ot.span("serve.sweep"):
+                    for r in queue.take(
+                            lambda r: r.uid in self._cancelled or _expired(r)):
+                        status = ("cancelled" if r.uid in self._cancelled
+                                  else "timeout")
+                        done_now.append(finish_queued(r, status))
+                    for idx in sorted(inflight):
+                        st = inflight[idx]
+                        if st.req.uid in self._cancelled:
+                            done_now.append(retire(idx, "cancelled"))
+                        elif _expired(st.req):
+                            done_now.append(retire(idx, "timeout"))
 
                 def admit_token(req, slot, tok):
                     """Post-prefill bookkeeping shared by both admission
@@ -578,7 +560,6 @@ class Scheduler:
                     and first-token time."""
                     nonlocal admit_seq
                     c_gen.inc()
-                    _G_TOKENS.inc()
                     prefix = getattr(req, "_prefix", None)
                     toks = ([] if prefix is None else
                             [int(t) for t in prefix]) + [tok]
@@ -678,8 +659,6 @@ class Scheduler:
                         admit_token(req, slot, tok)
                 m.gauge("queue_depth").set(len(queue))
                 m.gauge("slots_active").set(pool.n_active)
-                _G_QUEUE.set(len(queue))
-                _G_ACTIVE.set(pool.n_active)
                 if pages is not None:
                     set_page_gauges()
 
@@ -711,25 +690,31 @@ class Scheduler:
                     t1 = time.perf_counter()
                     try:
                         with _ot.span("serve.decode", active=pool.n_active,
-                                      paged=bool(pages is not None)) as dsp:
+                                      paged=bool(pages is not None)):
                             if pages is not None:
                                 # tables rebuilt every iteration: a retire
                                 # frees pages a NEW admission may re-map, and
                                 # a stale table would route an inactive
                                 # slot's decode write into the new owner's
                                 # live page
-                                tables_np = pages.table_array(n, max_pages)
-                                logits, cache = engine.paged_decode_step(
-                                    cache, tok_buf[:, None], pos_vec,
-                                    tables_np, page_size=ps)
-                            else:
-                                logits, cache = engine.decode_step(
-                                    cache, jnp.asarray(tok_buf[:, None]),
-                                    jnp.asarray(pos_vec))
-                            key, k = jax.random.split(key)
-                            toks = np.asarray(engine.sample(logits, k))
+                                with _ot.span("serve.tables"):
+                                    tables_np = pages.table_array(n, max_pages)
+                            # enqueue the step and the sampling ops; the
+                            # host blocks only on the sampled tokens
+                            with _ot.span("serve.dispatch"):
+                                if pages is not None:
+                                    logits, cache = engine.paged_decode_step(
+                                        cache, tok_buf[:, None], pos_vec,
+                                        tables_np, page_size=ps)
+                                else:
+                                    logits, cache = engine.decode_step(
+                                        cache, jnp.asarray(tok_buf[:, None]),
+                                        jnp.asarray(pos_vec))
+                                key, k = jax.random.split(key)
+                                sampled = engine.sample(logits, k)
+                            with _ot.span("serve.wait"):
+                                toks = np.asarray(sampled)
                             dt = time.perf_counter() - t1
-                            dsp.set(wall_us=round(dt * 1e6, 1))
                     except _fault.InjectedFault as e:
                         # the decode step itself is unservable (ladder
                         # exhausted at trace time — donated buffers are
@@ -741,24 +726,23 @@ class Scheduler:
                     else:
                         c_decode_s.inc(dt)
                         c_steps.inc()
-                        _G_DECODE_S.inc(dt)
-                        _G_STEPS.inc()
 
                         # -- retire finished sequences, advance the rest --
-                        for idx in sorted(inflight):
-                            st = inflight[idx]
-                            pool.advance(idx)  # the step wrote st's fed token
-                            if pages is not None:
-                                pages.advance(idx)  # bounds-checked vs mapping
-                            tok = int(toks[idx])
-                            st.tokens.append(tok)
-                            c_gen.inc()
-                            _G_TOKENS.inc()
-                            if ((eos is not None and tok == eos)
-                                    or len(st.tokens) >= st.req.max_new_tokens):
-                                done_now.append(retire(idx))
-                            else:
-                                tok_buf[idx] = tok
+                        with _ot.span("serve.advance"):
+                            for idx in sorted(inflight):
+                                st = inflight[idx]
+                                pool.advance(idx)  # the step wrote its token
+                                if pages is not None:
+                                    pages.advance(idx)  # bounds-checked
+                                tok = int(toks[idx])
+                                st.tokens.append(tok)
+                                c_gen.inc()
+                                if ((eos is not None and tok == eos)
+                                        or len(st.tokens)
+                                        >= st.req.max_new_tokens):
+                                    done_now.append(retire(idx))
+                                else:
+                                    tok_buf[idx] = tok
 
                 if draining and not pool.n_active and queue:
                     # graceful drain: flush never-to-be-admitted requests

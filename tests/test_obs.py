@@ -1,7 +1,10 @@
 """Observability layer (`repro.obs`): span nesting under exceptions,
 ring-buffer overflow semantics, histogram percentile correctness vs numpy,
 zero-overhead-when-off guarantees (no events + bit-identical dispatch),
-metric registry lifecycle, and cross-process trace-file schema validation."""
+metric registry lifecycle, cross-process trace-file schema validation, and
+the profiler bridge (spans in a ``jax.profiler`` trace's host plane)."""
+import contextlib
+import glob
 import json
 import os
 import subprocess
@@ -250,3 +253,69 @@ class TestExport:
         names = [e["name"]
                  for e in json.loads(out.read_text())["traceEvents"]]
         assert names == ["outer", "inner", "tick", "inner", "outer"]
+
+
+# ---------------------------------------------------------------------------
+# Profiler bridge
+# ---------------------------------------------------------------------------
+
+
+@contextlib.contextmanager
+def profiled(log_dir):
+    """A ``jax.profiler`` session collecting host events, as the chip
+    benchmark's traced window runs one."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def host_spans(log_dir, prefixes):
+    """(name, start_ns, end_ns, stats) of the host-plane events of the one
+    ``.xplane.pb`` under ``log_dir`` whose names start with ``prefixes``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(prefixes):
+                        out.append((ev.name, ev.start_ns,
+                                    ev.start_ns + ev.duration_ns,
+                                    dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+class TestProfilerBridge:
+    def test_spans_reach_the_profiler_with_recording_off(self, tmp_path):
+        trace.set_enabled(False)
+        obs.reset()
+        try:
+            with profiled(tmp_path):
+                with trace.span("bridge.outer", n=3) as sp:
+                    with trace.span("bridge.inner"):
+                        trace.instant("bridge.tick")
+                    sp.set(done=1)
+            assert trace.events() == []  # the ring stays empty
+        finally:
+            trace.set_enabled(None)
+        (outer, inner) = host_spans(tmp_path, ("bridge.",))
+        assert (outer[0], inner[0]) == ("bridge.outer", "bridge.inner")
+        assert outer[1] <= inner[1] and inner[2] <= outer[2]
+        assert outer[3] == {"n": 3, "done": 1}
+
+    def test_recording_spans_also_reach_the_profiler(self, obs_on, tmp_path):
+        with profiled(tmp_path):
+            with trace.span("bridge.rec", k="v"):
+                pass
+        assert [e["name"] for e in trace.events()] == ["bridge.rec"] * 2
+        ((name, _s, _e, stats),) = host_spans(tmp_path, ("bridge.",))
+        assert name == "bridge.rec" and stats == {"k": "v"}

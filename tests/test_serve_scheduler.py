@@ -398,3 +398,83 @@ class TestStatsLifecycle:
         b = sched.stats
         assert a["completed_requests"] == b["completed_requests"] == 3
         assert b["generated_tokens"] == a["generated_tokens"]  # not 2x
+
+
+# ---------------------------------------------------------------------------
+# Host spans in a profiler trace
+# ---------------------------------------------------------------------------
+
+
+DECODE_CHILDREN = {True: ["serve.tables", "serve.dispatch", "serve.wait"],
+                   False: ["serve.dispatch", "serve.wait"]}
+
+
+def _profiled_spans(log_dir, run):
+    """Run ``run()`` under a profiler session collecting host events (as
+    the chip benchmark's traced window does) and return the ``serve.*``
+    spans of the trace's host plane as (name, start_ns, end_ns)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                        for ev in line.events
+                        if ev.name.startswith("serve.")]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _inside(outer, spans):
+    """The spans of ``spans`` that lie within ``outer``, in start order."""
+    return [s for s in spans if s is not outer
+            and outer[1] <= s[1] and s[2] <= outer[2]]
+
+
+class TestProfilerSpans:
+    @pytest.mark.parametrize("paged", [True, False],
+                             ids=["paged", "contiguous"])
+    def test_decode_iteration_spans_nest(self, engine, paged, tmp_path):
+        """Each decode iteration writes serve.iter > serve.decode >
+        {tables (paged), dispatch, wait} and serve.advance after it into
+        the profiler's host plane with recording off, properly nested."""
+        from repro import obs
+
+        engine.scfg.max_new_tokens = 4
+        trace = synthetic_trace(3, seed=5, vocab=engine.cfg.vocab_size,
+                                prompt_lens=(3, 6), new_tokens=(2, 4))
+        sched = Scheduler(engine, n_slots=2, prefill_chunk=4, paged=paged,
+                          page_size=4 if paged else None)
+        obs.set_enabled(False)
+        try:
+            spans = _profiled_spans(tmp_path, lambda: sched.run(trace))
+            assert obs.events() == []
+        finally:
+            obs.set_enabled(None)
+        iters = [s for s in spans if s[0] == "serve.iter"]
+        decodes = [s for s in spans if s[0] == "serve.decode"]
+        assert len(decodes) == sched.stats["decode_steps"] > 0
+        for it in iters:
+            kids = _inside(it, spans)
+            assert kids[0][0] == "serve.sweep"
+            for a, b in zip(kids, kids[1:]):  # nested or disjoint
+                assert b[1] >= a[2] or b[2] <= a[2]
+        for dec in decodes:
+            (it,) = [s for s in iters if s[1] <= dec[1] and dec[2] <= s[2]]
+            children = _inside(dec, spans)
+            assert [c[0] for c in children] == DECODE_CHILDREN[paged]
+            for a, b in zip(children, children[1:]):
+                assert a[2] <= b[1]
+            after = [s for s in _inside(it, spans) if s[1] >= dec[2]]
+            assert after and after[0][0] == "serve.advance"

@@ -9,6 +9,8 @@ these tests say nothing about results or speed.
 The topology is described only inside the module fixture, once a test of
 this file runs: the TPU library admits one loader per process.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -55,6 +57,7 @@ def _compile(one_chip, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the Pallas kernel is in the program
+    return text
 
 
 # qwen2-0.5b column-wise 50% linears: (rows, d_in, n_tiles, k_kept, tile)
@@ -144,3 +147,34 @@ def test_paged_attention_compiles(one_chip, case):
 def test_flash_attention_compiles(one_chip):
     _compile(one_chip, flash_attention_pallas, ((14, 512, 64), BF16),
              ((14, 512, 64), BF16), ((14, 512, 64), BF16))
+
+
+# one small case per kernel family (``pltpu_compat.KERNEL_FAMILIES``)
+TAGGED = {
+    "colwise_nm": (colwise_nm_matmul_pallas,
+                   [((8, 896), BF16), ((1, 448, 128), BF16), ((1, 448), I32)]),
+    "im2col_pack": (lambda x: im2col_pack_pallas(x, 3, 3, 1, 1),
+                    [((256, 1, 14, 14), BF16)]),
+    "conv_fused": (lambda x, v, i: conv2d_fused_pallas(
+        x, v, i, kh=3, kw=3, stride=1, pad=1), _conv_shapes("s3.c2")[1]),
+    "conv_fused_banded": (lambda x, v, i: conv2d_fused_banded_pallas(
+        x, v, i, kh=3, kw=3, stride=1, pad=1), _conv_shapes("s3.c2")[1]),
+    "paged_attn": (lambda *a: paged_attention_pallas(
+        *a, page_size=16, block_q=8),
+        [((8, 1, 14, 64), BF16), ((8, 1, 2, 64), BF16),
+         ((8, 1, 2, 64), BF16), ((129, 16, 2, 64), BF16),
+         ((129, 16, 2, 64), BF16), ((8, 16), I32), ((8,), I32)]),
+    "flash_attn": (flash_attention_pallas,
+                   [((14, 512, 64), BF16)] * 3),
+}
+
+
+@pytest.mark.parametrize("family", sorted(TAGGED))
+def test_compiled_kernel_carries_its_family_tag(one_chip, family):
+    """The compiled custom call carries the ``pallas_call`` metadata as
+    ``frontend_attributes={kernel_metadata=...}``: the stable name a
+    profiler trace shows for the kernel."""
+    fn, shapes = TAGGED[family]
+    text = _compile(one_chip, fn, *shapes)
+    tags = re.findall(r'kernel_metadata=\{\s*"kernel":\s*"(\w+)"', text)
+    assert tags and set(tags) == {family}
