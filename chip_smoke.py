@@ -221,17 +221,20 @@ def paged_kernel(chk: Checks, batch: int = 8,
     q = jax.random.normal(ks[0], (batch, 1, h, d)).astype(jnp.bfloat16)
     kn = jax.random.normal(ks[1], (batch, 1, kv, d)).astype(jnp.bfloat16)
     vn = jax.random.normal(ks[2], (batch, 1, kv, d)).astype(jnp.bfloat16)
-    kp = jax.random.normal(ks[3], (n_pages, ps, kv, d)).astype(jnp.bfloat16)
-    vp = jax.random.normal(ks[4], (n_pages, ps, kv, d)).astype(jnp.bfloat16)
+    # two layers of the [L, P, ps, KV*D] cache; the kernel reads layer 1
+    kp = jax.random.normal(ks[3], (2, n_pages, ps, kv * d)).astype(
+        jnp.bfloat16)
+    vp = jax.random.normal(ks[4], (2, n_pages, ps, kv * d)).astype(
+        jnp.bfloat16)
     tables = rng.permutation(n_pages - 1)[:batch * n_max].reshape(
         batch, n_max).astype(np.int32)
     lengths = rng.integers(0, n_max * ps, batch).astype(np.int32)
     lengths[0] = 0  # empty cache: only the new key is attended
     y = jax.jit(lambda *a: paged_attention_pallas(
-        *a, page_size=ps, interpret=interpret))(q, kn, vn, kp, vp, tables,
-                                                lengths)
+        *a, 1, page_size=ps, interpret=interpret))(q, kn, vn, kp, vp, tables,
+                                                   lengths)
     f32 = [t.astype(jnp.float32) for t in (q, kn, vn, kp, vp)]
-    want = paged_attention_ref(*f32, tables, lengths)
+    want = paged_attention_ref(*f32, tables, lengths, 1)
     chk.err(f"paged_attention B={batch} H={h} KV={kv} D={d} ps={ps}",
             rel_err(y, want), TOL_PAGED)
 

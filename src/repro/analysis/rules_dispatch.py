@@ -99,8 +99,8 @@ def recompute_vmem(spec, key) -> Optional[int]:
         hd = key.get("hd", key.d_in)
         kv = max(key.k_kept, 1)
         h = key.d_out // max(hd, 1)
-        return pk.paged_vmem_bytes(geom["ps"], kv, hd, geom["bq"], h,
-                                   sn=geom["bq"], in_bytes=ib)
+        return pk.paged_vmem_bytes(geom["ps"], geom["ppb"], kv, hd, h,
+                                   in_bytes=ib)
     return None
 
 

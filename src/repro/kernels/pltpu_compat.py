@@ -50,6 +50,25 @@ def make_async_copy(src_ref, dst_ref, sem_ref):
     return pltpu.make_async_copy(src_ref, dst_ref, sem_ref)
 
 
+class GuardedCopies:
+    """Several async copies started and waited as one descriptor, each only
+    where its predicate holds (the same predicate on both sides, so a wait
+    never blocks on a copy that was not started): a descriptor for
+    :func:`double_buffer_rotate` whose chunk is a variable number of
+    windows, such as the pages of a ragged final block."""
+
+    def __init__(self, copies):
+        self.copies = list(copies)  # [(predicate, descriptor)]
+
+    def start(self):
+        for pred, copy in self.copies:
+            pl.when(pred)(copy.start)
+
+    def wait(self):
+        for pred, copy in self.copies:
+            pl.when(pred)(copy.wait)
+
+
 def dma_semaphores(n: int):
     """Scratch-shape entry for ``n`` DMA completion semaphores."""
     return pltpu.SemaphoreType.DMA((n,))

@@ -392,15 +392,18 @@ def cache_write(cache_k, cache_v, k_news, v_news, pos):
 
 def paged_cache_init(cfg: ModelConfig, n_pages: int, page_size: int,
                      n_layers: int, dtype):
-    """Physical paged cache: [L, n_pages + 1, page_size, KV, D].
+    """Physical paged cache: [L, n_pages + 1, page_size, KV * D].
 
-    The extra page at index ``n_pages`` is the trash page — the write
-    target for padded page-table entries (inactive slots, rows past a
-    sequence's mapping). It may hold arbitrary junk; reads are always
-    masked by the per-sequence length, so nothing ever attends to it.
+    A page row holds every KV head's D values side by side, so a page is
+    one clean ``[page_size, KV*D]`` tile of the TPU's 128-lane layout (the
+    paged kernel's own view; see ``kernels/flash_attn/paged.py``). The
+    extra page at index ``n_pages`` is the trash page — the write target
+    for padded page-table entries (inactive slots, rows past a sequence's
+    mapping). It may hold arbitrary junk; reads are always masked by the
+    per-sequence length, so nothing ever attends to it.
     """
     kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-    shape = (n_layers, n_pages + 1, page_size, kv, hd)
+    shape = (n_layers, n_pages + 1, page_size, kv * hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -419,32 +422,41 @@ def page_rows(tables, seq_idx, pos, page_size: int):
 def paged_cache_write(cache_k, cache_v, k_news, v_news, rows):
     """Scatter the step's new K/V through page-table rows.
 
-    cache_*: [L, P, page_size, KV, D]; *_news: [L, N, KV, D]; rows: [N]
+    cache_*: [L, P, page_size, KV*D]; *_news: [L, N, KV, D]; rows: [N]
     flat physical row per token (from :func:`page_rows`). Inactive slots'
     rows all alias the trash page — duplicate scatter targets there are
     fine because those rows are never read.
     """
-    l, p, ps, kv, hd = cache_k.shape
-    fk = cache_k.reshape(l, p * ps, kv, hd)
-    fv = cache_v.reshape(l, p * ps, kv, hd)
-    fk = fk.at[:, rows].set(k_news.astype(cache_k.dtype))
-    fv = fv.at[:, rows].set(v_news.astype(cache_v.dtype))
-    return fk.reshape(cache_k.shape), fv.reshape(cache_v.shape)
+    l, p, ps, f = cache_k.shape
+    n = k_news.shape[1]
+    # one scatter over the flat [L * P * ps, F] rows: the scattered dim is
+    # the major one in the cache's own layout, so XLA relays nothing out
+    flat = (jnp.arange(l, dtype=jnp.int32)[:, None] * (p * ps)
+            + jnp.asarray(rows, jnp.int32)[None, :]).reshape(-1)
+
+    def write(cache, news):
+        out = cache.reshape(l * p * ps, f).at[flat].set(
+            news.reshape(l * n, f).astype(cache.dtype))
+        return out.reshape(cache.shape)
+
+    return write(cache_k, k_news), write(cache_v, v_news)
 
 
 def paged_attn_decode(
     params,
     cfg: ModelConfig,
     x: jax.Array,
-    layer_cache: Tuple[jax.Array, jax.Array],
+    cache: Tuple[jax.Array, jax.Array],
     *,
+    layer,
     pos: jax.Array,
     tables: jax.Array,
     page_size: int,
 ):
     """One-token decode against a paged READ-ONLY cache.
 
-    x [B, 1, d]; layer_cache (k_pages, v_pages): [P, page_size, KV, D];
+    x [B, 1, d]; cache (k_pages, v_pages): [L, P, page_size, KV*D], every
+    layer's pages, of which this is layer ``layer`` (an int32 scalar);
     pos [B] int32 per-slot lengths; tables [B, n_max] int32 page tables.
     Same no-write-in-scan contract as :func:`attn_decode` — returns
     (out, (k_new, v_new)) and the caller scatters through the page table
@@ -453,10 +465,10 @@ def paged_attn_decode(
     b = x.shape[0]
     pos_b = jnp.broadcast_to(jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,)), (b,))
     q, k_new, v_new = _qkv(params, cfg, x, pos_b[:, None], None)
-    kc, vc = layer_cache
+    kc, vc = cache
     from repro.kernels.flash_attn import paged_attention
 
-    o = paged_attention(q, k_new, v_new, kc, vc, tables, pos_b,
+    o = paged_attention(q, k_new, v_new, kc, vc, tables, pos_b, layer,
                         page_size=page_size)
     o = o.reshape(b, 1, -1)
     return linear_apply(params["o"], o), (k_new, v_new)

@@ -163,17 +163,18 @@ def shared_block_decode(params, cfg: ModelConfig, h, h0, layer_cache, *, pos):
     return h + out, new_cache
 
 
-def block_paged_decode(params, cfg: ModelConfig, h, layer_cache, *, pos,
+def block_paged_decode(params, cfg: ModelConfig, h, cache, *, layer, pos,
                        tables, page_size: int):
     """One-token decode through a transformer block against a paged cache.
 
-    layer_cache (k_pages, v_pages): [P, page_size, KV, D]; pos [B]; tables
-    [B, n_max].  Returns (h, (k_new, v_new)) — the caller scatters through
-    the page table after the layer scan (same contract as block_decode).
+    cache (k_pages, v_pages): [L, P, page_size, KV*D], read at ``layer``;
+    pos [B]; tables [B, n_max].  Returns (h, (k_new, v_new)) — the caller
+    scatters through the page table after the layer scan (same contract as
+    block_decode).
     """
     x = norm_apply(params["ln1"], h, cfg.norm)
     a, new_kv = attn.paged_attn_decode(
-        params["attn"], cfg, x, layer_cache, pos=pos, tables=tables,
+        params["attn"], cfg, x, cache, layer=layer, pos=pos, tables=tables,
         page_size=page_size,
     )
     h = h + a

@@ -474,7 +474,7 @@ def paged_decode_step(params, cfg: ModelConfig, cache, tokens: jax.Array,
     """One decode step against a paged KV cache (serve.kv_pages tier).
 
     tokens [B, 1]; pos [B] int32 per-slot lengths; tables [B, n_max] int32
-    page tables; ``cache`` leaves are [L, P, page_size, KV, D] (P includes
+    page tables; ``cache`` leaves are [L, P, page_size, KV*D] (P includes
     the trash page). Returns (logits [B, 1, V], new_cache). Same
     no-write-in-scan contract as :func:`decode_step`: the layers' new K/V
     come out as scan ys and ONE page-table scatter commits them.
@@ -491,13 +491,17 @@ def paged_decode_step(params, cfg: ModelConfig, cache, tokens: jax.Array,
 
     def body(carry, xs):
         hh, = carry
-        lp, kc, vc = xs
-        hh, (kn, vn) = block_paged_decode(lp, cfg, hh, (kc, vc), pos=pos_b,
-                                          tables=tables, page_size=page_size)
+        lp, li = xs
+        # the kernel reads layer li's pages from the whole cache: a per-layer
+        # slice would be a copy of the layer's pages every step
+        hh, (kn, vn) = block_paged_decode(
+            lp, cfg, hh, (cache["k"], cache["v"]), layer=li, pos=pos_b,
+            tables=tables, page_size=page_size)
         return (hh,), (kn, vn)
 
+    n_layers = cache["k"].shape[0]
     (h,), (k_news, v_news) = jax.lax.scan(
-        body, (h,), (params["layers"], cache["k"], cache["v"]))
+        body, (h,), (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
     # k_news [L, B, 1, KV, D] -> [L, B, KV, D]; one scatter through the
     # tables (inactive slots' rows land on the trash page)
     rows = attn_mod.page_rows(tables, jnp.arange(b, dtype=jnp.int32), pos_b,

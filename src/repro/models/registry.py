@@ -132,7 +132,7 @@ def prefill_packed_fn(cfg: ModelConfig, page_size: int):
 
 
 def paged_cache_init_fn(cfg: ModelConfig, n_pages: int, page_size: int):
-    """Physical paged cache ([L, n_pages + 1, page_size, KV, D] per leaf;
+    """Physical paged cache ([L, n_pages + 1, page_size, KV * D] per leaf;
     the +1 is the trash page)."""
     _require_paged_family(cfg, "paged cache")
     from repro.models import attention as attn_mod

@@ -171,7 +171,9 @@ class Scheduler:
                      race the PAGED_ATTN_GEOMETRY layouts for this shape
     kv_budget_rows : total physical KV rows for the paged pool (the memory
                      budget admission is charged against); defaults to
-                     n_slots * max_len, i.e. the contiguous pool's footprint
+                     n_slots * max_len rounded up to whole pages per slot,
+                     i.e. the contiguous pool's footprint: every slot can
+                     hold a max-length request at any page size
     alloc          : paged allocation policy. ``"reserve"`` (default) maps a
                      request's full prompt+budget up front — admitted never
                      OOMs, but EOS-early requests strand their unused tail
@@ -389,9 +391,9 @@ class Scheduler:
                     max_len, q_rows=n, dtype=cfg.dtype,
                     profile=bool(engine.scfg.profile_dispatch))
             ps = self.page_size
-            budget_rows = self.kv_budget_rows or n * max_len
-            n_pages = budget_rows // ps
             max_pages = -(-max_len // ps)
+            budget_rows = self.kv_budget_rows or n * max_pages * ps
+            n_pages = budget_rows // ps
             if n_pages < max_pages:
                 raise ValueError(
                     f"kv_budget_rows={budget_rows} ({n_pages} pages of {ps}) "

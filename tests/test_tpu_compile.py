@@ -25,6 +25,7 @@ from repro.kernels.conv_gemm.kernel import (
     conv2d_fused_banded_pallas,
     conv2d_fused_pallas,
 )
+from repro.dispatch import PAGED_ATTN_GEOMETRY
 from repro.kernels.flash_attn.kernel import flash_attention_pallas
 from repro.kernels.flash_attn.paged import paged_attention_pallas
 from repro.kernels.im2col_pack.kernel import im2col_pack_pallas
@@ -125,23 +126,28 @@ def test_conv2d_fused_banded_compiles(one_chip, layer):
         x, v, i, kh=k, kw=k, stride=stride, pad=k // 2), *shapes)
 
 
-# paged decode: (batch, heads, kv_heads, head_dim, page_size, block_q)
+# paged attention: (batch, Sq, heads, kv_heads, head_dim, page_size,
+# pages_per_block, block_q, table rows); "gen256" is the benchmark's decode
+# step at the default geometry
+_PS, _PPB = (dict(PAGED_ATTN_GEOMETRY[0])[k] for k in ("ps", "ppb"))
 PAGED = {
-    "qwen2-0.5b": (8, 14, 2, 64, 16, 8),
-    "qwen2-7b": (8, 28, 4, 128, 16, 8),
-    "qwen2-0.5b_ps32_bq16": (8, 14, 2, 64, 32, 16),
+    "qwen2-0.5b": (8, 1, 14, 2, 64, 16, 8, 8, 256),
+    "qwen2-7b": (8, 1, 28, 4, 128, 16, 8, 8, 256),
+    "qwen2-0.5b_ps32_bq16": (8, 40, 14, 2, 64, 32, 4, 16, 256),
+    "qwen2-0.5b_gen256": (64, 1, 14, 2, 64, _PS, _PPB, 8, 384),
 }
 
 
 @pytest.mark.parametrize("case", sorted(PAGED))
 def test_paged_attention_compiles(one_chip, case):
-    b, h, kv, d, ps, bq = PAGED[case]
-    n_max = 16
-    pages = (b * n_max + 1, ps, kv, d)
+    b, sq, h, kv, d, ps, ppb, bq, rows = PAGED[case]
+    n_max = rows // ps
+    pages = (24, b * n_max + 1, ps, kv * d)
     _compile(one_chip, lambda *a: paged_attention_pallas(
-        *a, page_size=ps, block_q=bq),
-        ((b, 1, h, d), BF16), ((b, 1, kv, d), BF16), ((b, 1, kv, d), BF16),
-        (pages, BF16), (pages, BF16), ((b, n_max), I32), ((b,), I32))
+        *a, page_size=ps, pages_per_block=ppb, block_q=bq),
+        ((b, sq, h, d), BF16), ((b, sq, kv, d), BF16), ((b, sq, kv, d), BF16),
+        (pages, BF16), (pages, BF16), ((b, n_max), I32), ((b,), I32),
+        ((), I32))
 
 
 def test_flash_attention_compiles(one_chip):
@@ -160,10 +166,11 @@ TAGGED = {
     "conv_fused_banded": (lambda x, v, i: conv2d_fused_banded_pallas(
         x, v, i, kh=3, kw=3, stride=1, pad=1), _conv_shapes("s3.c2")[1]),
     "paged_attn": (lambda *a: paged_attention_pallas(
-        *a, page_size=16, block_q=8),
+        *a, page_size=16, pages_per_block=8),
         [((8, 1, 14, 64), BF16), ((8, 1, 2, 64), BF16),
-         ((8, 1, 2, 64), BF16), ((129, 16, 2, 64), BF16),
-         ((129, 16, 2, 64), BF16), ((8, 16), I32), ((8,), I32)]),
+         ((8, 1, 2, 64), BF16), ((2, 129, 16, 128), BF16),
+         ((2, 129, 16, 128), BF16), ((8, 16), I32), ((8,), I32),
+         ((), I32)]),
     "flash_attn": (flash_attention_pallas,
                    [((14, 512, 64), BF16)] * 3),
 }
