@@ -16,7 +16,7 @@ when the analyzed tree contains the real ``src/repro`` package.
 """
 from __future__ import annotations
 
-from typing import Iterable, List, Optional, Tuple
+from typing import Iterable, List, Optional
 
 from repro.analysis.engine import Context, Rule, register
 
@@ -60,7 +60,6 @@ def recompute_vmem(spec, key) -> Optional[int]:
     from repro.kernels.colwise_nm import kernel as ck
     from repro.kernels.conv_gemm import kernel as gk
     from repro.kernels.flash_attn import paged as pk
-    from repro.kernels.im2col_pack.ref import out_size
 
     family = spec.name.split("@")[0]
     geom = dict(spec.geometry)
@@ -81,16 +80,12 @@ def recompute_vmem(spec, key) -> Optional[int]:
             kh=key.get("kh"), kw=key.get("kw"), stride=key.get("s", 1),
             pad=key.get("p", 0))
     if family == "fused_banded_pallas":
-        c, h = key.get("c"), key.get("h")
-        w = key.get("w", h)
-        b = max(key.get("b", 1), 1)
-        ho = out_size(h, key.get("kh"), key.get("s", 1), key.get("p", 0))
-        wo = out_size(w, key.get("kw"), key.get("s", 1), key.get("p", 0))
-        _, band_rows = band_rows_for(gk, b, h, key, ho, wo, geom)
-        return gk.banded_vmem_bytes(c, w, band_rows, geom["v"],
-                                    min(geom["bk"], key.k_kept), tile,
-                                    in_bytes=ib,
-                                    taps=key.get("kh") * key.get("kw"))
+        return gk.banded_vmem_bytes(
+            key.get("c"), max(key.get("b", 1), 1), key.get("h"),
+            key.get("w", key.get("h")), geom["v"],
+            min(geom["bk"], key.k_kept), tile, in_bytes=ib,
+            kh=key.get("kh"), kw=key.get("kw"), stride=key.get("s", 1),
+            pad=key.get("p", 0), hb=geom["hb"])
     if family == "two_kernel_pipelined":
         return ck.pipelined_strips_vmem_bytes(
             key.d_in, geom["v"], geom["hb"], min(geom["bk"], key.k_kept),
@@ -102,12 +97,6 @@ def recompute_vmem(spec, key) -> Optional[int]:
         return pk.paged_vmem_bytes(geom["ps"], geom["ppb"], kv, hd, h,
                                    in_bytes=ib)
     return None
-
-
-def band_rows_for(gk, b, h, key, ho, wo, geom) -> Tuple[int, int]:
-    return gk.band_plan(b=b, h=h, kh=key.get("kh"), stride=key.get("s", 1),
-                        pad=key.get("p", 0), ho=ho, wo=wo, v=geom["v"],
-                        hb=geom["hb"])
 
 
 def _audit_pairs(ctx: Context):

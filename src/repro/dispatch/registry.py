@@ -655,30 +655,16 @@ def _apply_conv_pipelined(params, x, *, kh, kw, stride=1, pad=0, v=128,
                                        v=geom_v, block_k=geom_bk, hb=geom_hb)
 
 
-def _conv_hw(key: OpKey):
-    """(c, b, h, w, ho, wo) of a conv key (ho/wo recomputed from extras)."""
-    from repro.kernels.im2col_pack.ref import out_size
-
-    c, h = key.get("c"), key.get("h")
-    w = key.get("w", h)
-    b = max(key.get("b", 1), 1)
-    ho = out_size(h, key.get("kh"), key.get("s", 1), key.get("p", 0))
-    wo = out_size(w, key.get("kw"), key.get("s", 1), key.get("p", 0))
-    return c, b, h, w, ho, wo
-
-
 def _banded_vmem_for(geom_v: int, geom_bk: int, geom_hb: int):
     def vm(key: OpKey) -> int:
-        from repro.kernels.conv_gemm.kernel import band_plan, banded_vmem_bytes
+        from repro.kernels.conv_gemm.kernel import banded_vmem_bytes
 
-        c, b, h, w, ho, wo = _conv_hw(key)
-        _, band_rows = band_plan(b=b, h=h, kh=key.get("kh"),
-                                 stride=key.get("s", 1), pad=key.get("p", 0),
-                                 ho=ho, wo=wo, v=geom_v, hb=geom_hb)
-        return banded_vmem_bytes(c, w, band_rows, geom_v,
-                                 min(geom_bk, key.k_kept), min(key.tile, 512),
-                                 in_bytes=_key_itemsize(key),
-                                 taps=key.get("kh") * key.get("kw"))
+        return banded_vmem_bytes(
+            key.get("c"), max(key.get("b", 1), 1), key.get("h"),
+            key.get("w", key.get("h")), geom_v, min(geom_bk, key.k_kept),
+            min(key.tile, 512), in_bytes=_key_itemsize(key),
+            kh=key.get("kh"), kw=key.get("kw"), stride=key.get("s", 1),
+            pad=key.get("p", 0), hb=geom_hb)
 
     return vm
 

@@ -5,7 +5,7 @@
                      never exist in HBM (``conv2d_fused``); needs the whole
                      CNHW map VMEM-resident
   banded megakernel: the H-tiled variant (``conv2d_fused_banded``) — only a
-                     double-buffered row band of the map is resident, DMA'd
+                     double-buffered band window of the map is resident, DMA'd
                      per band while the previous band's GEMM runs; covers
                      stem-scale maps and batch > 1
   two-kernel       : fused im2col+pack kernel, then the strip-major sparse
@@ -37,13 +37,17 @@ from repro.kernels.colwise_nm.ops import (
     sparse_grad_dxg,
 )
 from repro.kernels.colwise_nm.ref import colwise_nm_matmul_ref
-from repro.kernels.im2col_pack.kernel import tap_coords
+from repro.kernels.im2col_pack.kernel import (
+    lane_plan,
+    shift_path,
+    tap_coords,
+)
 from repro.kernels.conv_gemm.kernel import (
     band_plan,
     conv2d_fused_banded_pallas,
     conv2d_fused_pallas,
 )
-from repro.kernels.im2col_pack.ops import im2col_pack
+from repro.kernels.im2col_pack.ops import counts_pack_path, im2col_pack
 from repro.kernels.im2col_pack.ref import out_size
 from repro.kernels.pltpu_compat import should_interpret
 
@@ -63,6 +67,7 @@ def compress_conv_weights(w_ohwi: jax.Array, cfg: SparsityConfig):
     return values, idx, meta
 
 
+@counts_pack_path
 @functools.partial(
     jax.jit, static_argnames=("kh", "kw", "stride", "pad", "v", "block_k"))
 def conv2d_fused(
@@ -94,6 +99,7 @@ def conv2d_fused(
     return y[:, : b * ho * wo].reshape(o, b, ho, wo)
 
 
+@counts_pack_path
 @functools.partial(
     jax.jit,
     static_argnames=("kh", "kw", "stride", "pad", "v", "block_k", "hb"))
@@ -111,9 +117,9 @@ def conv2d_fused_banded(
     hb: int = 2,
 ) -> jax.Array:
     """Banded megakernel conv: the H-tiled fused plan.  Only a double-buffered
-    row band (``hb`` strips of input rows + halo) is VMEM-resident; band s+1
-    is DMA'd while band s's gather+GEMM runs.  Same numerics/layout contract
-    as :func:`conv2d_fused`.  Returns CNHW output [O, B, Ho, Wo]."""
+    band window (``hb`` strips' input lanes or rows + halo) is VMEM-resident;
+    band s+1 is DMA'd while band s's packing+GEMM runs.  Same numerics/layout
+    contract as :func:`conv2d_fused`.  Returns CNHW output [O, B, Ho, Wo]."""
     c, b, h, w = x_cnhw.shape
     ho = out_size(h, kh, stride, pad)
     wo = out_size(w, kw, stride, pad)
@@ -185,14 +191,20 @@ def banded_bytes_moved(c: int, b: int, h: int, w: int, kh: int, stride: int,
                        pad: int, ho: int, wo: int, v: int, hb: int,
                        o: int, itemsize: int) -> int:
     """Analytic HBM traffic of the banded megakernel at band depth ``hb``:
-    every band DMAs its ``band_rows`` input-row window once (halo rows are
-    re-read by adjacent bands — that is the price of banding), and the
-    [O, P] output is written once.  Shallower bands re-read more halo;
-    deeper bands amortize it at the cost of double-buffer VMEM."""
-    n_bands, band_rows = band_plan(b=b, h=h, kh=kh, stride=stride, pad=pad,
-                                   ho=ho, wo=wo, v=v, hb=hb)
+    every band DMAs its window once — for the shift packer ``hb*v`` lanes
+    plus a lane halo on each side, for the select packer ``band_rows``
+    input rows — (halo is re-read by adjacent bands — that is the price of
+    banding), and the [O, P] output is written once.  Shallower bands
+    re-read more halo; deeper bands amortize it at the cost of
+    double-buffer VMEM."""
     n_strips = -(-b * ho * wo // v)
-    band_reads = n_bands * c * band_rows * w
+    if shift_path(stride=stride, h=h, w=w, ho=ho, wo=wo):
+        plan = lane_plan(b=b, h=h, w=w, pad=pad, v=v, hb=hb)
+        band_reads = plan.n_bands * c * plan.win
+    else:
+        n_bands, band_rows = band_plan(b=b, h=h, kh=kh, stride=stride,
+                                       pad=pad, ho=ho, wo=wo, v=v, hb=hb)
+        band_reads = n_bands * c * band_rows * w
     out_write = o * n_strips * v
     return (band_reads + out_write) * itemsize
 

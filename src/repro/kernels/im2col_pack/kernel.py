@@ -15,16 +15,26 @@ row depend on (kh, kw) but NOT on the channel, so a whole block of channels
 shares one gather: step (s, k, cc) emits the strip tile
 [s, k*C + cc*c_block : k*C + (cc+1)*c_block, :].
 
-The gather itself is built from what Mosaic lowers (no dynamic vector
-gather): the map is viewed as [C, B*H, W] rows, strip s reads a 16-aligned
-window of those rows (:func:`window_plan`), and :func:`tap_tile` selects each
-output position's (row, column) with two one-hot contractions — an MXU
-column select over W, then a masked row reduction over the window.  Both are
-exact: every output sums exactly one input element.
+Two packers build a tap's [c_block, V] tile, chosen from the conv's static
+shape (:func:`shift_path`):
+
+  - shift (stride 1, output grid equal to the input grid: every "same" conv)
+    — the map is viewed lane-flattened as [C, B*H*W] with a zero halo on
+    each side (:func:`lane_plan`); output position ``p`` at tap (kh, kw)
+    reads flat input ``p + (kh - pad)*W + (kw - pad)``, so the tile is a
+    static lane slice of the strip's aligned lane window, masked where the
+    tap leaves the map (:func:`shift_tile`).  A copy: no MXU work;
+  - select (strided or not "same") — the map is viewed as [C, B*H, W] rows,
+    strip s reads a 16-aligned window of those rows (:func:`window_plan`),
+    and :func:`tap_tile` selects each output position's (row, column) with
+    two one-hot contractions — an MXU column select over W, then a masked
+    row reduction over the window.  Exact: every output sums exactly one
+    input element.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +47,8 @@ from repro.kernels.im2col_pack.ref import out_size
 
 #: row alignment of a VMEM row window: the sublane tile of packed bf16 rows
 ROW_ALIGN = 16
+#: lane alignment of a lane window's origin and halo: the vreg lane width
+LANE = 128
 
 
 def tap_coords(p, *, ikh, ikw, stride, pad, b, h, w, ho, wo,
@@ -190,6 +202,75 @@ def tap_tile(win, org, s, *, ikh, ikw, stride, pad, b, h, w, ho, wo, v,
     return jnp.sum(cols * rsel.astype(jnp.float32)[None], axis=1)
 
 
+def shift_path(*, stride: int, h: int, w: int, ho: int, wo: int) -> bool:
+    """Whether a conv packs by shifted copies (:func:`shift_tile`): at
+    stride 1 with the output grid equal to the input grid, a tap moves every
+    output position by the same flat offset.  Other convs keep the one-hot
+    select of :func:`tap_tile`."""
+    return stride == 1 and (ho, wo) == (h, w)
+
+
+class LanePlan(NamedTuple):
+    """Static lane windows of the shift packer over a lane-flattened map.
+
+    The map is viewed as [C, B*H*W] lanes with ``halo`` zero lanes in front
+    (:func:`flatten_lanes`), so band g (``hb`` strips) reads the window of
+    lanes ``[g*hb*v, g*hb*v + win)``: its positions plus ``halo`` lanes on
+    each side, enough for every tap's offset.  ``lanes`` is the padded
+    map's length, so that the last band's window stays in bounds."""
+    halo: int
+    win: int
+    n_bands: int
+    lanes: int
+
+
+def lane_plan(*, b: int, h: int, w: int, pad: int, v: int,
+              hb: int = 1) -> LanePlan:
+    """:class:`LanePlan` of a "same" conv (:func:`shift_path`) packed in
+    strips of ``v`` positions, ``hb`` strips a band.  A tap moves a position
+    by at most ``pad*w + pad`` flat positions; the halo rounds that up to
+    whole lane tiles."""
+    n_strips = -(-b * h * w // v)
+    hb = max(min(hb, n_strips), 1)
+    n_bands = -(-n_strips // hb)
+    halo = ceil_to(pad * w + pad, LANE)
+    return LanePlan(halo=halo, win=hb * v + 2 * halo, n_bands=n_bands,
+                    lanes=n_bands * hb * v + 2 * halo)
+
+
+def flatten_lanes(x: jax.Array, plan: LanePlan) -> jax.Array:
+    """CNHW map -> [C, plan.lanes]: flattened (batch, h, w) positions after
+    ``plan.halo`` zero lanes, zero-padded at the end."""
+    c, b, h, w = x.shape
+    n = b * h * w
+    return jnp.pad(x.reshape(c, n),
+                   ((0, 0), (plan.halo, plan.lanes - plan.halo - n)))
+
+
+def lane_window(first_strip, *, v: int, lanes: int):
+    """Lane slice of the ``lanes``-wide window whose first position is
+    strip ``first_strip``'s, less the halo, in a buffer whose lane 0 is
+    the halo before position 0 (the flattened map, or a band's window
+    with ``first_strip`` counted from the band's first strip).  The origin
+    is a multiple of ``v``, so of the lane tile on the chip."""
+    return pl.ds(pl.multiple_of(first_strip * v, v), lanes)
+
+
+def shift_tile(win, s, *, ikh, ikw, pad, halo, b, h, w, v):
+    """[C, v] im2col rows of tap (ikh, ikw) for strip ``s`` of a "same"
+    conv, copied from ``win`` [C, v + 2*halo]: the map's flattened
+    positions from ``s*v - halo`` (:func:`lane_window`).  Position
+    ``s*v + j`` reads lane ``halo + j + (ikh - pad)*w + ikw - pad``, a
+    static lane slice; the mask of :func:`tap_coords` zeroes what lies
+    outside the map (row wrap-around, the top and bottom padding, batch
+    boundaries, positions past the end).  An exact copy in ``win``'s
+    dtype."""
+    valid = strip_tap_coords(s, v=v, ikh=ikh, ikw=ikw, stride=1, pad=pad,
+                             b=b, h=h, w=w, ho=h, wo=w)[0]
+    off = halo + (ikh - pad) * w + ikw - pad
+    return jnp.where(valid, win[:, off:off + v], jnp.zeros((), win.dtype))
+
+
 def _kernel(
     x_ref,
     o_ref,
@@ -206,14 +287,24 @@ def _kernel(
     wo: int,
     win_rows: int,
     bh_pad: int,
+    halo: int | None,
     interpret: bool,
 ):
     s = pl.program_id(0)
     k = pl.program_id(1)
+    # every channel of the block shares the tap's source coordinates: one
+    # copy or gather emits the whole [c_block, v] strip tile
+    if halo is not None:  # shift packer over the lane-flattened map
+        win = x_ref[:, lane_window(s, v=v, lanes=v + 2 * halo)]
+        for kk in range(kh * kw):  # static lane offsets: one branch a tap
+            @pl.when(k == kk)
+            def _tap(kk=kk):
+                o_ref[0] = shift_tile(
+                    win, s, ikh=kk // kw, ikw=kk % kw, pad=pad, halo=halo,
+                    b=b, h=h, w=w, v=v).astype(o_ref.dtype)
+        return
     top = first_row(s * v, h=h, ho=ho, wo=wo, stride=stride, pad=pad)
     org = window_origin(top, win_rows=win_rows, bh_pad=bh_pad)
-    # every channel of the block shares the tap's source coordinates: one
-    # gather emits the whole [c_block, v] strip tile
     tile = tap_tile(
         x_ref[:, pl.ds(org, win_rows), :], org, s, ikh=k // kw, ikw=k % kw,
         stride=stride, pad=pad, b=b, h=h, w=w, ho=ho, wo=wo, v=v,
@@ -222,13 +313,15 @@ def _kernel(
 
 
 def _choose_c_block(c: int, row_bytes: int, cap: int = 32,
-                    budget: int = 4 * 2 ** 20) -> int:
-    """Largest divisor of C no bigger than ``cap`` whose [c_block, B*H, W]
-    input block stays within ``budget`` bytes (grid-coarsening factor)."""
-    for cb in range(min(c, cap), 0, -1):
-        if c % cb == 0 and (cb * row_bytes <= budget or cb == 1):
-            return cb
-    return 1
+                    budget: int = 4 * 2 ** 20, align: int = 1) -> int:
+    """Largest divisor of C no bigger than ``cap`` whose input block of
+    ``row_bytes`` a channel stays within ``budget`` bytes (grid-coarsening
+    factor), else the smallest.  ``align``: a block whose channels are its
+    sublane dim takes a multiple of the packed sublane tile, or all of C."""
+    fits = [cb for cb in range(1, c + 1)
+            if c % cb == 0 and (cb % align == 0 or cb == c)]
+    within = [cb for cb in fits if cb <= cap and cb * row_bytes <= budget]
+    return max(within) if within else min(fits)
 
 
 def im2col_pack_pallas(
@@ -246,22 +339,33 @@ def im2col_pack_pallas(
     wo = out_size(w, kw, stride, pad)
     n_pos = b * ho * wo
     n_strips = -(-n_pos // v)
-    win_rows, bh_pad = strip_window(b=b, h=h, kh=kh, stride=stride, pad=pad,
-                                    ho=ho, wo=wo, v=v)
-    c_block = _choose_c_block(c, bh_pad * w * x.dtype.itemsize)
+    item = x.dtype.itemsize
+    if shift_path(stride=stride, h=h, w=w, ho=ho, wo=wo):
+        plan = lane_plan(b=b, h=h, w=w, pad=pad, v=v)
+        halo, win_rows, bh_pad = plan.halo, 0, 0
+        c_block = _choose_c_block(c, plan.lanes * item, align=ROW_ALIGN)
+        x_in = flatten_lanes(x, plan)
+        in_spec = pl.BlockSpec((c_block, plan.lanes),
+                               lambda s, k, cc: (cc, 0))
+    else:
+        halo = None
+        win_rows, bh_pad = strip_window(b=b, h=h, kh=kh, stride=stride,
+                                        pad=pad, ho=ho, wo=wo, v=v)
+        c_block = _choose_c_block(c, bh_pad * w * item)
+        x_in = pad_rows(x, bh_pad)
+        in_spec = pl.BlockSpec((c_block, bh_pad, w),
+                               lambda s, k, cc: (cc, 0, 0))
     n_cb = c // c_block
 
     grid = (n_strips, kh * kw, n_cb)
     out = pl.pallas_call(
         functools.partial(
             _kernel, kh=kh, kw=kw, stride=stride, pad=pad, v=v, b=b, h=h,
-            w=w, ho=ho, wo=wo, win_rows=win_rows, bh_pad=bh_pad,
+            w=w, ho=ho, wo=wo, win_rows=win_rows, bh_pad=bh_pad, halo=halo,
             interpret=interpret,
         ),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((c_block, bh_pad, w), lambda s, k, cc: (cc, 0, 0)),
-        ],
+        in_specs=[in_spec],
         out_specs=pl.BlockSpec(
             (1, c_block, v), lambda s, k, cc, _n=n_cb: (s, k * _n + cc, 0)
         ),
@@ -271,5 +375,5 @@ def im2col_pack_pallas(
         ),
         metadata=kernel_tag("im2col_pack"),
         interpret=interpret,
-    )(pad_rows(x, bh_pad))
+    )(x_in)
     return out

@@ -38,7 +38,11 @@ from repro.kernels.conv_gemm import (
     conv2d_two_kernel,
     conv2d_two_kernel_pipelined,
 )
-from repro.kernels.conv_gemm.kernel import _conv_step_vmem_bytes
+from repro.kernels.conv_gemm.kernel import (
+    _conv_step_vmem_bytes,
+    _select_window_bytes,
+    _shift_window_bytes,
+)
 from repro.kernels.im2col_pack import im2col_pack_ref, out_size
 from repro.kernels.im2col_pack.kernel import window_plan
 from repro.kernels.pltpu_compat import ceil_to
@@ -262,23 +266,24 @@ class TestPlanLadder:
 
     def test_banded_vmem_predicate_is_dtype_aware_of_double_buffer(self):
         # the same band geometry is feasible in bf16 but not f32, and the
-        # analytic model counts BOTH band buffers of the double buffer
+        # analytic model counts BOTH band buffers of the double buffer.  A
+        # strided conv: its bands are row windows (the select packer)
         spec = REGISTRY.get("conv", "fused_banded_pallas")
         hb = spec.geom("hb")
         # w chosen so hb*v does not divide wo: bands cross an output-row
         # boundary and the window carries the full stride+halo row count
         kw = dict(c=64, h=640, w=600, o=256, k_kept=288, tile=128)
-        f32 = dispatch.conv_key(kw["c"], kw["h"], kw["w"], kw["o"], 3, 3, 1,
+        f32 = dispatch.conv_key(kw["c"], kw["h"], kw["w"], kw["o"], 3, 3, 2,
                                 1, kw["k_kept"], kw["tile"], dtype="float32")
-        bf16 = dispatch.conv_key(kw["c"], kw["h"], kw["w"], kw["o"], 3, 3, 1,
+        bf16 = dispatch.conv_key(kw["c"], kw["h"], kw["w"], kw["o"], 3, 3, 2,
                                  1, kw["k_kept"], kw["tile"],
                                  dtype="bfloat16")
         assert spec.vmem_bytes(f32) > spec.vmem_bytes(bf16)
         assert not spec.feasible(f32)[0] and spec.feasible(bf16)[0]
-        ho = out_size(kw["h"], 3, 1, 1)
-        wo = out_size(kw["w"], 3, 1, 1)
+        ho = out_size(kw["h"], 3, 2, 1)
+        wo = out_size(kw["w"], 3, 2, 1)
         v = spec.geom("v")
-        _, rows = band_plan(b=1, h=kw["h"], kh=3, stride=1, pad=1, ho=ho,
+        _, rows = band_plan(b=1, h=kw["h"], kh=3, stride=2, pad=1, ho=ho,
                             wo=wo, v=v, hb=hb)
         # one band buffer as the kernel allocates it: an aligned row window
         # over the lane-padded map
@@ -287,7 +292,29 @@ class TestPlanLadder:
         one_band = kw["c"] * win_rows * w_pad * 4
         assert spec.vmem_bytes(f32) >= 2 * one_band
         # beyond the per-step working set it counts exactly two buffers
-        step = _conv_step_vmem_bytes(kw["c"], w_pad, win_rows, 9, v,
+        window = _select_window_bytes(kw["c"], w_pad, win_rows, v, 4)
+        step = _conv_step_vmem_bytes(window, kw["c"], 9, v,
+                                     min(spec.geom("bk"), kw["k_kept"]),
+                                     kw["tile"], 4)
+        assert spec.vmem_bytes(f32) - step == 2 * one_band
+
+    def test_banded_vmem_predicate_counts_both_lane_windows(self):
+        # a "same" stride-1 conv's bands are lane windows of the flattened
+        # map (the shift packer): the predicate is dtype-aware and counts
+        # both windows of the double buffer, each hb strips plus a lane halo
+        # on each side wide
+        spec = REGISTRY.get("conv", "fused_banded_pallas")
+        v, hb = spec.geom("v"), spec.geom("hb")
+        kw = dict(c=512, h=8, w=1000, o=256, k_kept=288, tile=128)
+        f32, bf16 = (dispatch.conv_key(kw["c"], kw["h"], kw["w"], kw["o"], 3,
+                                       3, 1, 1, kw["k_kept"], kw["tile"],
+                                       dtype=dt)
+                     for dt in ("float32", "bfloat16"))
+        assert not spec.feasible(f32)[0] and spec.feasible(bf16)[0]
+        halo = ceil_to(kw["w"] + 1, 128)  # pad*w + pad lanes, tile-aligned
+        one_band = kw["c"] * (hb * v + 2 * halo) * 4
+        window = _shift_window_bytes(kw["c"], halo, v, 4)
+        step = _conv_step_vmem_bytes(window, kw["c"], 9, v,
                                      min(spec.geom("bk"), kw["k_kept"]),
                                      kw["tile"], 4)
         assert spec.vmem_bytes(f32) - step == 2 * one_band

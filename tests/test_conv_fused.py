@@ -255,10 +255,10 @@ class TestConvLayerAbstraction:
         # the same map geometry can be feasible in bf16 but not f32
         spec = REGISTRY.get("conv", "fused_sparse_pallas")
         kw = dict(kh=3, kw=3, stride=1, pad=1, k_kept=1152, tile=128)
-        f32 = dispatch.conv_key(256, 64, 64, 256, kw["kh"], kw["kw"],
+        f32 = dispatch.conv_key(256, 96, 96, 256, kw["kh"], kw["kw"],
                                 kw["stride"], kw["pad"], kw["k_kept"],
                                 kw["tile"], dtype="float32")
-        bf16 = dispatch.conv_key(256, 64, 64, 256, kw["kh"], kw["kw"],
+        bf16 = dispatch.conv_key(256, 96, 96, 256, kw["kh"], kw["kw"],
                                  kw["stride"], kw["pad"], kw["k_kept"],
                                  kw["tile"], dtype="bfloat16")
         assert spec.vmem_bytes(f32) > spec.vmem_bytes(bf16)

@@ -95,6 +95,7 @@ CONV = {
     "s4.c2": (512, 7, 512, 3, 1, 1),
     "s2.c2.b4": (128, 28, 128, 3, 1, 4),
     "stem.b8": (64, 112, 64, 3, 2, 8),
+    "s1.c.b32": (64, 56, 64, 3, 1, 32),   # ResNet-18 at the benchmark's batch
 }
 
 
@@ -119,7 +120,8 @@ def test_conv2d_fused_compiles(one_chip, layer):
         x, v, i, kh=k, kw=k, stride=stride, pad=k // 2), *shapes)
 
 
-@pytest.mark.parametrize("layer", ["s3.c2", "s2.c2.b4", "stem.b8"])
+@pytest.mark.parametrize("layer", ["s3.c2", "s2.c2.b4", "stem.b8",
+                                   "s1.c.b32"])
 def test_conv2d_fused_banded_compiles(one_chip, layer):
     (k, stride), shapes = _conv_shapes(layer)
     _compile(one_chip, lambda x, v, i: conv2d_fused_banded_pallas(
